@@ -32,12 +32,15 @@ quantization error. The quantize->dequantize op sequence is
 jnp ``value_codec`` path runs — so the two routes are bit-exact per tile
 (docs/DESIGN.md §10).
 
-Bit-exactness contract (asserted in tests/test_megakernel.py): every
-intermediate uses the same op sequence as the jnp reference in
-``fed.engine.aggregate_updates`` — in particular the weighted sum is a
-dot_general ([1,C] @ [C,T]), which XLA lowers identically to the reference's
-``einsum("k,kn->n")`` — so agg and residuals match the traced jnp path bit
-for bit, per-tile, including the all-True tie masks of all-zero rows.
+Bit-exactness contract (asserted in tests/test_megakernel.py on the CPU and
+by chip_smoke.py on a TPU): every intermediate uses the same op sequence as
+the jnp reference in ``fed.engine.aggregate_updates`` — so agg and residuals
+match the traced jnp path bit for bit, per-tile, including the all-True tie
+masks of all-zero rows. The weighted sum is an f32 multiply and a sum over
+the client axis: XLA on a TPU lowers the reference's ``einsum("k,kn->n")``
+(and the per-leaf ``tensordot``, at any matmul precision) to exactly that,
+while a Mosaic ``dot_general`` runs on the MXU and rounds differently at
+DEFAULT and HIGHEST precision alike (measured on a v5e at C=2 and C=8).
 
 ``active`` gating mirrors the engine's padded-cohort semantics: inactive
 rows contribute nothing to the merge or the overlap counts and their
@@ -96,10 +99,8 @@ def _fused_merge_kernel(ef: bool, opwa: bool, gamma: float, d: int,
         vals = vals * act_ref[...]
         mask = mask & act_b
 
-    # [1, C] @ [C, T]: the same dot_general the reference einsum lowers to
-    weighted = jax.lax.dot_general(
-        w_ref[...], vals, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # [1, T]
+    # multiply and sum over clients, not a dot: see the module docstring
+    weighted = jnp.sum(w_ref[...] * vals, axis=0, keepdims=True)   # [1, T]
     if opwa:
         counts = jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
         amplify = (counts > 0) & (counts <= d)

@@ -1,6 +1,6 @@
 """jit'd public wrappers for the Pallas kernels: padding/reshaping to tile
-boundaries, CPU interpret-mode autodetection, flat-vector interfaces used by
-repro.core."""
+boundaries, interpret mode on the CPU backend only, flat-vector interfaces
+used by repro.core."""
 from __future__ import annotations
 
 import functools
@@ -21,7 +21,13 @@ from repro.kernels.threshold_find import TILE_N as THRESH_TILE
 
 
 def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
+    """Compiled on a TPU, interpreted on the CPU backend (the test suite);
+    no other backend can run these kernels, so it is refused outright."""
+    platform = jax.devices()[0].platform
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas TPU kernels cannot run on platform {platform!r}")
+    return platform == "cpu"
 
 
 def _pad_rows(n_rows: int) -> int:

@@ -21,11 +21,13 @@ reads by widening the bisection to a 16-ary search:
     ``lo`` is exactly the k-th-largest bit pattern (ties kept), bit-identical
     to the 32-halving reference for every k in [1, n].
 
-Per-client retained counts ``ks [C, 1]`` arrive as a scalar-prefetch operand
-(SMEM), so they stay fully traced — one compiled kernel serves every BCRS
-schedule. The optional ``e2d`` input switches the selection quantity to the
-error-feedback ``corrected = residuals + updates`` without materializing it
-in HBM.
+Per-client retained counts ``ks [C, 1]`` arrive as one ``(C, 1)`` VMEM block
+that every grid step maps to, so they stay fully traced — one compiled
+kernel serves every BCRS schedule. (Mosaic loads only scalars from SMEM, so
+a scalar-prefetch operand could not be compared against the ``[C, W-1]``
+counts as a vector.) The optional ``e2d`` input switches the selection
+quantity to the error-feedback ``corrected = residuals + updates`` without
+materializing it in HBM.
 
 ``emit_scale`` additionally returns the per-client row absmax
 ``max_j |corrected_ij|`` — the quantity a symmetric quantizer's scale is
@@ -60,10 +62,10 @@ TILE_N = 512
 _STEP0 = np.uint32((1 << 31) // WAYS)
 
 
-def _threshold_find_kernel(has_res: bool, emit_scale: bool, ks_ref, x_ref,
-                           *rest):
+def _threshold_find_kernel(has_res: bool, emit_scale: bool, x_ref, *rest):
     rest = list(rest)
     e_ref = rest.pop(0) if has_res else None
+    ks_ref = rest.pop(0)
     th_ref = rest.pop(0)
     sc_ref = rest.pop(0) if emit_scale else None
     lo_ref, cnt_ref = rest
@@ -115,11 +117,12 @@ def _threshold_find_kernel(has_res: bool, emit_scale: bool, ks_ref, x_ref,
         cnt = cnt_ref[...]
         k = ks_ref[...]                                     # [C, 1] i32
         qual = cnt >= k
-        jvec = (jax.lax.broadcasted_iota(jnp.uint32, (1, WAYS - 1), 1)
-                + jnp.uint32(1))
-        jsel = jnp.max(jnp.where(qual, jvec, jnp.uint32(0)),
+        # the boundary index is reduced in int32: Mosaic has no unsigned
+        # reductions (j <= 15, so the cast back is exact)
+        jvec = jax.lax.broadcasted_iota(jnp.int32, (1, WAYS - 1), 1) + 1
+        jsel = jnp.max(jnp.where(qual, jvec, 0),
                        axis=1, keepdims=True)               # [C, 1]
-        new_lo = lo + jsel * step
+        new_lo = lo + jsel.astype(jnp.uint32) * step
         lo_ref[...] = new_lo
 
         @pl.when(s == SWEEPS - 1)
@@ -145,29 +148,27 @@ def threshold_find_pallas(x2d: jax.Array, ks: jax.Array,
     c, n = x2d.shape
     assert n % TILE_N == 0, f"n={n} must be a multiple of {TILE_N}"
     nt = n // TILE_N
-    bs = pl.BlockSpec((c, TILE_N), lambda s, t, *_: (0, t))
+    bs = pl.BlockSpec((c, TILE_N), lambda s, t: (0, t))
+    col = pl.BlockSpec((c, 1), lambda s, t: (0, 0))
     in_specs, args = [bs], [x2d]
     if e2d is not None:
         in_specs.append(bs)
         args.append(e2d)
-    col = pl.BlockSpec((c, 1), lambda s, t, *_: (0, 0))
+    in_specs.append(col)
+    args.append(ks.astype(jnp.int32))
     out_specs = [col, col] if emit_scale else col
     out_shape = jax.ShapeDtypeStruct((c, 1), jnp.uint32)
     if emit_scale:
         out_shape = [out_shape, jax.ShapeDtypeStruct((c, 1), jnp.float32)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(SWEEPS, nt),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((c, 1), jnp.uint32),
-                        pltpu.VMEM((c, WAYS - 1), jnp.int32)],
-    )
     out = pl.pallas_call(
         functools.partial(_threshold_find_kernel, e2d is not None,
                           emit_scale),
-        grid_spec=grid_spec,
+        grid=(SWEEPS, nt),
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((c, 1), jnp.uint32),
+                        pltpu.VMEM((c, WAYS - 1), jnp.int32)],
         interpret=interpret,
-    )(ks.astype(jnp.int32), *args)
+    )(*args)
     return (out[0], out[1]) if emit_scale else out

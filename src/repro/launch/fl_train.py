@@ -49,6 +49,7 @@ from repro.fed import engine as engine_mod
 from repro.fed.mesh_round import make_mesh_round_step
 from repro.fed.simulation import _link_columns, cohort_slots, plan_cohort
 from repro.ft import FailureInjector, StragglerPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 
 #: scan-chunk cap when no checkpoint cadence is configured — keeps the
@@ -225,7 +226,8 @@ def _stack_batches(cfg: FLTrainConfig, vocab: int, rounds: List[int],
 
 def run(cfg: FLTrainConfig) -> dict:
     """Train per ``cfg``; returns {params, residuals, losses,
-    executed_rounds, wall_per_round, chunk_rounds, times, resumed_from}."""
+    executed_rounds, wall_per_round, chunk_rounds, compile_s, times,
+    resumed_from} (``compile_s``: seconds spent compiling scan chunks)."""
     model_cfg = get_config(cfg.arch)
     if cfg.reduced:
         model_cfg = model_cfg.reduced()
@@ -299,6 +301,7 @@ def run(cfg: FLTrainConfig) -> dict:
     losses: List[float] = []
     wall_per_round: List[float] = []
     chunk_rounds: List[int] = []
+    compile_s = 0.0
     kw = dict(strategy=cfg.strategy, eta=cfg.eta, gamma=cfg.gamma,
               overlap_d=cfg.overlap_d, use_kernel=cfg.use_kernel)
 
@@ -344,7 +347,9 @@ def run(cfg: FLTrainConfig) -> dict:
             # makes equal-length chunks ONE executable, so wall_per_round
             # reports steady-state dispatch cost
             if len(idx) not in compiled:
+                t0 = time.perf_counter()
                 compiled[len(idx)] = sim.compile(params, residuals, xs)
+                compile_s += time.perf_counter() - t0
             t0 = time.perf_counter()
             out = compiled[len(idx)](params, residuals, xs)
             jax.block_until_ready(out["params"])
@@ -382,7 +387,8 @@ def run(cfg: FLTrainConfig) -> dict:
     return {"params": params, "residuals": residuals, "losses": losses,
             "executed_rounds": [plan.rounds[i] for i in todo],
             "wall_per_round": wall_per_round, "chunk_rounds": chunk_rounds,
-            "times": times, "resumed_from": resumed_from}
+            "compile_s": compile_s, "times": times,
+            "resumed_from": resumed_from}
 
 
 def _run_async(cfg: FLTrainConfig, model, model_cfg, params, links, strat,
@@ -649,6 +655,7 @@ def _run_population(cfg: FLTrainConfig, model, model_cfg, params, plan,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="stablelm-1.6b")
     ap.add_argument("--rounds", type=int, default=10)

@@ -24,11 +24,13 @@ from repro.core.bcrs import pod_link_schedule
 from repro.data import synthetic_lm_tokens
 from repro.dist.grad_sync import (init_compressed_state,
                                   make_compressed_train_step, make_train_step)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.optim import make_optimizer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="stablelm-1.6b")
     ap.add_argument("--steps", type=int, default=50)

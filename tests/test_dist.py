@@ -11,6 +11,7 @@ from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.dist import sharding as shd
 from repro.dist.grad_sync import (init_compressed_state,
                                   make_compressed_train_step, make_train_step)
+from repro.launch.mesh import make_mesh_from_spec
 from repro.models import Model
 from repro.optim import make_optimizer
 
@@ -18,7 +19,7 @@ B, S = 4, 32
 
 
 def _mesh(axes=("data", "model")):
-    return jax.make_mesh((1,) * len(axes), axes)
+    return make_mesh_from_spec((1,) * len(axes), axes)
 
 
 def _batch(cfg, b=B, s=S):
