@@ -76,6 +76,11 @@ def make_round_body(loss_fn: Callable, *, lr_local: float = 1e-2,
 
     The reported loss is the active-masked mean of each client's last real
     local step's pre-update loss (``make_masked_local_trainer`` semantics).
+
+    The round's phases run under ``jax.named_scope``s, ``fl.local_train``,
+    ``fl.merge`` and ``fl.server_step``: names in the compiled program's
+    ``op_name`` metadata, by which a profile can split the round's device
+    time. They change no arithmetic.
     """
     strat = strat_mod.get(strategy)   # config-time error, names listed
     ef = strat.needs_residuals
@@ -88,8 +93,9 @@ def make_round_body(loss_fn: Callable, *, lr_local: float = 1e-2,
     def body(params, residuals, batches, step_mask, coeffs, crs, active):
         if ef and residuals is None:
             raise ValueError(f"{strategy} needs per-leaf residuals")
-        deltas, losses = jax.vmap(local_train, in_axes=(None, 0, 0))(
-            params, batches, step_mask)
+        with jax.named_scope("fl.local_train"):
+            deltas, losses = jax.vmap(local_train, in_axes=(None, 0, 0))(
+                params, batches, step_mask)
         w = coeffs.astype(jnp.float32)
         if active is not None:
             w = jnp.where(active, w, 0.0)
@@ -99,20 +105,24 @@ def make_round_body(loss_fn: Callable, *, lr_local: float = 1e-2,
             aggregation operate on the leaf's natural (TP-sharded) layout —
             reshape(c, -1) would merge sharded dims and force XLA to gather
             the whole leaf per device (§Perf iteration 1)."""
-            if not compress:
-                dl32 = dl.astype(jnp.float32)
-                if active is not None:
-                    dl32 = dl32 * active.reshape(
-                        (-1,) + (1,) * (dl32.ndim - 1))
-                agg, new_res = jnp.tensordot(w, dl32, axes=(0, 0)), res
-            else:
-                n = dl.size // dl.shape[0]
-                ks = comp.k_for_ratio_traced(n, crs)
-                agg, new_res = compress_merge_leaf(
-                    dl, w, ks, gamma=gamma, overlap_d=overlap_d, opwa=opwa,
-                    use_kernel=use_kernel, residuals=res, active=active,
-                    value_codec=value_codec, kernel_codec=kernel_codec)
-            return (p.astype(jnp.float32) - eta * agg).astype(p.dtype), new_res
+            with jax.named_scope("fl.merge"):
+                if not compress:
+                    dl32 = dl.astype(jnp.float32)
+                    if active is not None:
+                        dl32 = dl32 * active.reshape(
+                            (-1,) + (1,) * (dl32.ndim - 1))
+                    agg, new_res = jnp.tensordot(w, dl32, axes=(0, 0)), res
+                else:
+                    n = dl.size // dl.shape[0]
+                    ks = comp.k_for_ratio_traced(n, crs)
+                    agg, new_res = compress_merge_leaf(
+                        dl, w, ks, gamma=gamma, overlap_d=overlap_d,
+                        opwa=opwa, use_kernel=use_kernel, residuals=res,
+                        active=active, value_codec=value_codec,
+                        kernel_codec=kernel_codec)
+            with jax.named_scope("fl.server_step"):
+                new_p = (p.astype(jnp.float32) - eta * agg).astype(p.dtype)
+            return new_p, new_res
 
         leaves_p, treedef = jax.tree.flatten(params)
         leaves_d = treedef.flatten_up_to(deltas)

@@ -59,4 +59,5 @@ def block_topk_pallas(x2d: jax.Array, k: int, *, interpret: bool = True):
         out_shape=[jax.ShapeDtypeStruct((nb, block), x2d.dtype),
                    jax.ShapeDtypeStruct((nb, block), jnp.int8)],
         interpret=interpret,
+        name="block_topk",
     )(x2d)

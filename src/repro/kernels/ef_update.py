@@ -57,4 +57,5 @@ def ef_update_pallas(g2d: jax.Array, e2d: jax.Array, k: int,
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.float32),
                    jax.ShapeDtypeStruct((nb, block), jnp.float32)],
         interpret=interpret,
+        name="ef_update",
     )(g2d, e2d)

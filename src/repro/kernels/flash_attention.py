@@ -71,4 +71,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -183,6 +183,7 @@ def fused_merge_pallas(x2d: jax.Array, thresholds: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_merge",
     )(*args)
     if n_pad:
         if ef:

@@ -48,4 +48,5 @@ def overlap_combine_pallas(vals: jax.Array, masks: jax.Array,
         out_specs=out,
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="overlap_combine",
     )(vals, masks.astype(jnp.int8), coeffs.reshape(k, 1))

@@ -170,5 +170,6 @@ def threshold_find_pallas(x2d: jax.Array, ks: jax.Array,
         scratch_shapes=[pltpu.VMEM((c, 1), jnp.uint32),
                         pltpu.VMEM((c, WAYS - 1), jnp.int32)],
         interpret=interpret,
+        name="threshold_find",
     )(*args)
     return (out[0], out[1]) if emit_scale else out

@@ -27,6 +27,7 @@ including the EF residual state).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 from dataclasses import dataclass
@@ -100,6 +101,7 @@ class FLTrainConfig:
     use_kernel: object = "auto"
     seed: int = 0
     verbose: bool = True
+    trace_dir: str = ""          # non-empty: profile the run into this dir
 
     def __post_init__(self):
         strat_mod.get(self.strategy)   # config-time error, names listed
@@ -227,7 +229,20 @@ def _stack_batches(cfg: FLTrainConfig, vocab: int, rounds: List[int],
 def run(cfg: FLTrainConfig) -> dict:
     """Train per ``cfg``; returns {params, residuals, losses,
     executed_rounds, wall_per_round, chunk_rounds, compile_s, times,
-    resumed_from} (``compile_s``: seconds spent compiling scan chunks)."""
+    resumed_from} (``compile_s``: seconds spent compiling scan chunks).
+
+    With ``cfg.trace_dir`` the run is profiled (``jax.profiler.trace``) into
+    that directory: the device's ops, under the round body's scopes and the
+    kernels' names, on one timeline with the host spans of the scan and
+    round loops (``fl.stage``, ``fl.compile``, ``fl.dispatch``,
+    ``fl.wait``, ``fl.account``, ``fl.checkpoint``)."""
+    trace = (jax.profiler.trace(cfg.trace_dir) if cfg.trace_dir
+             else contextlib.nullcontext())
+    with trace:
+        return _run(cfg)
+
+
+def _run(cfg: FLTrainConfig) -> dict:
     model_cfg = get_config(cfg.arch)
     if cfg.reduced:
         model_cfg = model_cfg.reduced()
@@ -329,6 +344,9 @@ def run(cfg: FLTrainConfig) -> dict:
                   f"round_time {times.per_round[-1].actual:.2f}s "
                   f"CRs [{crs_act.min():.3f},{crs_act.max():.3f}]")
 
+    # host spans: on the profiler's timeline beside the device's ops when
+    # the run is traced, about a microsecond each when it is not
+    span = jax.profiler.TraceAnnotation
     if cfg.engine == "scan":
         sim = engine_mod.make_mesh_sim_scan(model.loss_fn, params,
                                             lr=cfg.lr, **kw)
@@ -336,48 +354,63 @@ def run(cfg: FLTrainConfig) -> dict:
         pos = 0
         while pos < len(todo):
             idx = todo[pos:pos + chunk]
-            xs = {"batches": _stack_batches(cfg, model_cfg.vocab_size,
-                                            [plan.rounds[i] for i in idx],
-                                            c_max),
-                  "step_mask": jnp.asarray(plan.step_mask[idx]),
-                  "active": jnp.asarray(plan.active[idx]),
-                  "weights": jnp.asarray(plan.weights[idx]),
-                  "crs": jnp.asarray(plan.crs[idx])}
+            with span("fl.stage"):
+                xs = {"batches": _stack_batches(
+                          cfg, model_cfg.vocab_size,
+                          [plan.rounds[i] for i in idx], c_max),
+                      "step_mask": jnp.asarray(plan.step_mask[idx]),
+                      "active": jnp.asarray(plan.active[idx]),
+                      "weights": jnp.asarray(plan.weights[idx]),
+                      "crs": jnp.asarray(plan.crs[idx])}
             # AOT-compile once per distinct chunk length; the jit cache
             # makes equal-length chunks ONE executable, so wall_per_round
             # reports steady-state dispatch cost
             if len(idx) not in compiled:
                 t0 = time.perf_counter()
-                compiled[len(idx)] = sim.compile(params, residuals, xs)
+                with span("fl.compile"):
+                    compiled[len(idx)] = sim.compile(params, residuals, xs)
                 compile_s += time.perf_counter() - t0
             t0 = time.perf_counter()
-            out = compiled[len(idx)](params, residuals, xs)
-            jax.block_until_ready(out["params"])
+            with span("fl.dispatch"):
+                out = compiled[len(idx)](params, residuals, xs)
+            with span("fl.wait"):
+                jax.block_until_ready(out["params"])
             wall = (time.perf_counter() - t0) / len(idx)
             params, residuals = out["params"], out["residuals"]
-            for j, i in enumerate(idx):
-                account_and_log(i, float(out["ys"]["loss"][j]), wall)
+            with span("fl.account"):
+                # one transfer for the chunk's losses, not one per round
+                chunk_losses = jax.device_get(out["ys"]["loss"])
+                for j, i in enumerate(idx):
+                    account_and_log(i, float(chunk_losses[j]), wall)
             chunk_rounds.append(len(idx))
-            save(plan.rounds[idx[-1]] + 1)
+            with span("fl.checkpoint"):
+                save(plan.rounds[idx[-1]] + 1)
             pos += len(idx)
     elif cfg.engine == "round":
         step = make_mesh_round_step(model.loss_fn, lr_local=cfg.lr, **kw)
         for pos, i in enumerate(todo):
-            batches = {k: jnp.asarray(v) for k, v in _round_batches(
-                cfg, model_cfg.vocab_size, plan.rounds[i], c_max).items()}
+            with span("fl.stage"):
+                batches = {k: jnp.asarray(v) for k, v in _round_batches(
+                    cfg, model_cfg.vocab_size, plan.rounds[i],
+                    c_max).items()}
             t0 = time.perf_counter()
-            params, residuals, loss = step(
-                params, residuals if ef else None, batches,
-                jnp.asarray(plan.step_mask[i]), jnp.asarray(plan.weights[i]),
-                jnp.asarray(plan.crs[i]), jnp.asarray(plan.active[i]))
-            jax.block_until_ready(params)
+            with span("fl.dispatch"):
+                params, residuals, loss = step(
+                    params, residuals if ef else None, batches,
+                    jnp.asarray(plan.step_mask[i]),
+                    jnp.asarray(plan.weights[i]),
+                    jnp.asarray(plan.crs[i]), jnp.asarray(plan.active[i]))
+            with span("fl.wait"):
+                jax.block_until_ready(params)
             wall = time.perf_counter() - t0
             if not ef:
                 residuals = jnp.zeros((0,), jnp.float32)
-            account_and_log(i, float(loss), wall)
+            with span("fl.account"):
+                account_and_log(i, float(loss), wall)
             chunk_rounds.append(1)
             if (pos + 1) % chunk == 0 or pos == len(todo) - 1:
-                save(plan.rounds[i] + 1)
+                with span("fl.checkpoint"):
+                    save(plan.rounds[i] + 1)
     else:
         raise ValueError(f"unknown engine {cfg.engine!r}")
 
@@ -707,6 +740,9 @@ def main():
                     help="cohort slots C in population mode "
                          "(0 = reuse --clients)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace-dir", default="",
+                    help="profile the run into this directory (device ops "
+                         "and the fl.* host spans; off when empty)")
     args = ap.parse_args()
     run(FLTrainConfig(
         arch=args.arch, rounds=args.rounds, clients=args.clients,
@@ -724,7 +760,7 @@ def main():
         async_p_fail=args.async_p_fail, async_timeout_s=args.async_timeout,
         async_version_ring=args.async_version_ring,
         async_batch_dispatch=not args.async_sequential_dispatch,
-        seed=args.seed))
+        seed=args.seed, trace_dir=args.trace_dir))
 
 
 if __name__ == "__main__":

@@ -310,6 +310,26 @@ class TestFlTrainDriver:
                         jax.tree.leaves(loop["params"])):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    def test_trace_dir_holds_the_host_spans(self, tmp_path):
+        """``trace_dir`` profiles the run: the written trace's host plane
+        holds the scan loop's spans, on the device ops' timeline."""
+        import glob
+        from jax.profiler import ProfileData
+        res = self._run(rounds=2, strategy="bcrs_opwa", checkpoint_every=1,
+                        checkpoint_dir=str(tmp_path / "ckpt"),
+                        trace_dir=str(tmp_path / "trace"))
+        assert res["executed_rounds"] == [0, 1]
+        files = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                          recursive=True)
+        assert len(files) == 1
+        host = [p for p in ProfileData.from_file(files[0]).planes
+                if p.name == "/host:CPU"]
+        assert len(host) == 1
+        names = {e.name for line in host[0].lines for e in line.events}
+        for span in ("fl.stage", "fl.compile", "fl.dispatch", "fl.wait",
+                     "fl.account", "fl.checkpoint"):
+            assert span in names, span
+
     def test_resumes_legacy_params_only_checkpoint(self, tmp_path):
         """A checkpoint from the pre-scan driver (bare params pytree, no
         'params/' prefix, no residual state) must actually LOAD — not

@@ -73,3 +73,62 @@ def test_compiles_for_v5e(case, one_chip):
             for k in kinds]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_round_kernels_are_named_in_the_v5e_program(one_chip, monkeypatch):
+    """In the fl_train scan chunk compiled for the chip, each Mosaic call is
+    named after its kernel (the label a profile shows), and its op_name
+    holds the kernel's name inside the round's merge scope."""
+    import re
+
+    from repro.kernels import ops as kops
+
+    # the kernels' jitted wrappers keep their traces: drop the CPU ones
+    # (interpret mode) before, and the chip's (Mosaic) ones after
+    jax.clear_caches()
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    try:
+        hlo, leaves = _compile_round_for(one_chip)
+    finally:
+        jax.clear_caches()
+    calls = [re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*op_name=\"([^\"]*)\"",
+                      line).groups()
+             for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 2 * leaves
+    for kernel in ("threshold_find", "fused_merge"):
+        mine = [(n, op) for n, op in calls if n.startswith(kernel + ".")]
+        assert len(mine) == leaves, kernel
+        for n, op in mine:
+            parts = op.split("/")
+            assert "fl.merge" in parts and kernel in parts, (n, op)
+
+
+def _compile_round_for(one_chip):
+    """The HLO of a one-round fl_train scan chunk of a tiny model, compiled
+    for the described chip with the Pallas kernels on the merge path, and
+    the model's number of parameter leaves."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.fed import engine
+    from repro.models import Model
+
+    cfg = dataclasses.replace(
+        get_config("stablelm-1.6b").reduced(), n_layers=1, d_model=32,
+        n_heads=2, n_kv_heads=2, head_dim=16, d_ff=64, vocab_size=256)
+    model = Model(cfg)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda s: place(s.shape, s.dtype), shapes)
+    t, c, s, b, seq = 1, 2, 1, 1, 16
+    xs = {"batches": {"tokens": place((t, c, s, b, seq), jnp.int32),
+                      "labels": place((t, c, s, b, seq), jnp.int32)},
+          "step_mask": place((t, c, s), jnp.bool_),
+          "active": place((t, c), jnp.bool_),
+          "weights": place((t, c), jnp.float32),
+          "crs": place((t, c), jnp.float32)}
+    sim = engine.make_mesh_sim_scan(model.loss_fn, shapes, lr=0.01,
+                                    strategy="bcrs_opwa", use_kernel=True)
+    hlo = sim.compile(params, place((0,), jnp.float32), xs).as_text()
+    return hlo, len(jax.tree.leaves(shapes))
