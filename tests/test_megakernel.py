@@ -17,7 +17,7 @@ from repro.core.opwa import opwa_aggregate_traced_k
 from repro.fed import engine
 from repro.kernels import ops, ref
 from repro.kernels.fused_merge import fused_merge_pallas
-from repro.kernels.threshold_find import threshold_find_pallas
+from repro.kernels.threshold_find import block_lanes, threshold_find_pallas
 
 STRATEGIES = ("fedavg", "topk", "eftopk", "bcrs", "bcrs_opwa")
 
@@ -81,6 +81,56 @@ class TestThresholdFind:
         np.testing.assert_array_equal(
             np.asarray(th_ef),
             np.asarray(ref.threshold_find_ref(u, ks, e))[:, 0])
+
+
+def _tiled_width(c, size, ef):
+    """A width that spans 3 blocks and ends in a partial block and a partial
+    folded chunk ("blocks"), or one smaller than one block ("leaf"), from
+    the kernel's own block width."""
+    w = block_lanes(c, 1 << 40, ef)
+    return 2 * w + w // 2 + 384 if size == "blocks" else 3 * 1024 + 384
+
+
+class TestThresholdFindTiling:
+    """The block/chunk tiling of threshold_find, bit for bit against the
+    32-halving reference: k=1, k=n, ties straddling the threshold across
+    blocks, and an all-zero row, through the kernel and its padding
+    wrapper."""
+
+    @pytest.mark.parametrize("mode", ["plain", "ef", "ef_scale"])
+    @pytest.mark.parametrize("size", ["blocks", "leaf"])
+    @pytest.mark.parametrize("c", [1, 4, 9])
+    def test_vs_ref(self, c, size, mode):
+        ef = mode != "plain"
+        n = _tiled_width(c, size, ef)
+        u, e, _, ks = _case(c, n, seed=c + n)
+        # 300 tied magnitudes spread over every block, with k inside them
+        ties = np.arange(300) * (n // 300)
+        u = u.at[0, ties].set(jnp.where(ties % 2 == 0, 5.0, -5.0))
+        e = e.at[0, ties].set(0.0)
+        ks = ks.at[0].set(150)
+        if c > 1:
+            u = u.at[1].set(0.0)                   # all-zero row
+            e = e.at[1].set(0.0)
+            ks = ks.at[2].set(1).at[c - 1].set(n)
+        res = e if ef else None
+        want = np.asarray(ref.threshold_find_ref(u, ks, res))
+        if mode == "ef_scale":
+            th, absmax = threshold_find_pallas(u, ks.reshape(c, 1), e,
+                                               emit_scale=True)
+            np.testing.assert_array_equal(
+                np.asarray(absmax),
+                np.asarray(jnp.max(jnp.abs(e + u), axis=1, keepdims=True)))
+        else:
+            th = threshold_find_pallas(u, ks.reshape(c, 1), res)
+            # the wrapper pads a ragged width back up to the kernel's
+            cut = n - 37
+            u_cut, k_cut = u[:, :cut], jnp.minimum(ks, cut)
+            r_cut = None if res is None else res[:, :cut]
+            np.testing.assert_array_equal(
+                np.asarray(ops.topk_thresholds(u_cut, k_cut, r_cut)),
+                np.asarray(ref.threshold_find_ref(u_cut, k_cut, r_cut))[:, 0])
+        np.testing.assert_array_equal(np.asarray(th), want)
 
 
 class TestFusedMerge:

@@ -19,6 +19,10 @@ from repro.kernels.threshold_find import threshold_find_pallas
 
 #: 8 clients x the stablelm-1.6b MLP weight (d_model 2048 x d_ff 5632)
 C, N = 8, 2048 * 5632
+#: the merge cell's cohort: its largest leaf (the 100352 x 2048 embedding)
+#: and a norm-sized leaf (2048, already a multiple of megakernel_aggregate's
+#: 1024 padding)
+C_MERGE, N_EMBED, N_NORM = 4, 100352 * 2048, 2048
 
 
 @pytest.fixture(scope="module")
@@ -56,19 +60,25 @@ def _fm_ef_int8(x, th, w, e, act, scales):
 
 
 CASES = {
-    "threshold_find": (_tf_plain, ("x", "ks")),
-    "threshold_find_ef_scale": (_tf_ef_scale, ("x", "ks", "x")),
-    "fused_merge_opwa": (_fm_opwa, ("x", "th", "col", "col")),
+    "threshold_find": (_tf_plain, ("x", "ks"), (C, N)),
+    "threshold_find_ef_scale": (_tf_ef_scale, ("x", "ks", "x"), (C, N)),
+    "threshold_find_embedding": (_tf_plain, ("x", "ks"), (C_MERGE, N_EMBED)),
+    "threshold_find_ef_scale_embedding": (_tf_ef_scale, ("x", "ks", "x"),
+                                          (C_MERGE, N_EMBED)),
+    "threshold_find_norm": (_tf_plain, ("x", "ks"), (C_MERGE, N_NORM)),
+    "threshold_find_ef_scale_norm": (_tf_ef_scale, ("x", "ks", "x"),
+                                     (C_MERGE, N_NORM)),
+    "fused_merge_opwa": (_fm_opwa, ("x", "th", "col", "col"), (C, N)),
     "fused_merge_ef_int8": (_fm_ef_int8, ("x", "th", "col", "x", "col",
-                                          "col")),
+                                          "col"), (C, N)),
 }
 
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_compiles_for_v5e(case, one_chip):
-    fn, kinds = CASES[case]
-    shapes = {"x": ((C, N), jnp.float32), "ks": ((C, 1), jnp.int32),
-              "th": ((C, 1), jnp.uint32), "col": ((C, 1), jnp.float32)}
+    fn, kinds, (c, n) = CASES[case]
+    shapes = {"x": ((c, n), jnp.float32), "ks": ((c, 1), jnp.int32),
+              "th": ((c, 1), jnp.uint32), "col": ((c, 1), jnp.float32)}
     args = [jax.ShapeDtypeStruct(*shapes[k], sharding=one_chip)
             for k in kinds]
     compiled = jax.jit(fn).lower(*args).compile()
