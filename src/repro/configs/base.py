@@ -13,24 +13,39 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                # routed experts the router scores
     top_k: int
     d_expert: int                 # per-expert FFN hidden dim
     n_shared: int = 0             # shared (always-on) experts
     d_shared: int = 0             # shared expert hidden dim (0 -> d_expert)
     first_dense_layers: int = 0   # leading layers that use a dense FFN instead
-    capacity_factor: float = 1.25
+    # routed experts held on this chip: the first n_held of n_experts (0 ->
+    # all). The layer computes only their part of each token's output.
+    n_held: int = 0
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum to 1
+    routed_scaling_factor: float = 1.0
     router_noise: float = 0.0
     aux_loss_weight: float = 0.001
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: q = x @ wq, no q-LoRA
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # YaRN rope scaling (DeepSeek-V2's rope_scaling); factor 1 is plain RoPE
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -97,6 +112,14 @@ class ModelConfig:
     remat: str = "full"           # full | dots | none
     source: str = ""
 
+    def __post_init__(self):
+        # sections given as dicts (a JSON configuration file's) become
+        # their dataclasses
+        for name, cls in _SECTIONS.items():
+            value = getattr(self, name)
+            if isinstance(value, dict):
+                object.__setattr__(self, name, cls(**value))
+
     # ------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
@@ -123,7 +146,10 @@ class ModelConfig:
             if self.mla is not None:
                 m = self.mla
                 qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-                n += d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk
+                if m.q_lora_rank is None:
+                    n += d * self.n_heads * qk
+                else:
+                    n += d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk
                 n += d * (m.kv_lora_rank + m.qk_rope_head_dim)
                 n += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
                 n += self.n_heads * m.v_head_dim * d
@@ -135,7 +161,7 @@ class ModelConfig:
             if self.moe is not None and layer >= self.moe.first_dense_layers:
                 mo = self.moe
                 n += d * mo.n_experts                       # router
-                n += mo.n_experts * 3 * d * mo.d_expert     # routed experts
+                n += mo.held * 3 * d * mo.d_expert          # routed experts
                 ds = mo.d_shared or mo.d_expert
                 n += mo.n_shared * 3 * d * ds               # shared experts
             elif self.rwkv is None:
@@ -169,7 +195,7 @@ class ModelConfig:
         if self.moe is None:
             return self.n_params()
         mo = self.moe
-        dense_expert_params = mo.n_experts * 3 * self.d_model * mo.d_expert
+        dense_expert_params = mo.held * 3 * self.d_model * mo.d_expert
         active_expert_params = mo.top_k * 3 * self.d_model * mo.d_expert
         n_moe_layers = self.n_layers - mo.first_dense_layers
         return self.n_params() - n_moe_layers * (dense_expert_params - active_expert_params)
@@ -190,13 +216,15 @@ class ModelConfig:
             remat="none",
         )
         if self.moe is not None:
-            kw["moe"] = MoEConfig(n_experts=4, top_k=2, d_expert=32,
-                                  n_shared=self.moe.n_shared, d_shared=32,
-                                  first_dense_layers=min(1, self.moe.first_dense_layers),
-                                  capacity_factor=2.0)
+            kw["moe"] = dataclasses.replace(
+                self.moe, n_experts=4, top_k=2, d_expert=32, d_shared=32,
+                first_dense_layers=min(1, self.moe.first_dense_layers),
+                n_held=0)
         if self.mla is not None:
-            kw["mla"] = MLAConfig(q_lora_rank=32, kv_lora_rank=16,
-                                  qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+            kw["mla"] = dataclasses.replace(
+                self.mla, q_lora_rank=self.mla.q_lora_rank and 32,
+                kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16)
         if self.ssm is not None:
             kw["ssm"] = SSMConfig(state_size=4, expand=2, head_dim=16, chunk=16)
         if self.rwkv is not None:
@@ -213,6 +241,11 @@ class ModelConfig:
         if self.mtp_depth:
             kw["mtp_depth"] = 1
         return dataclasses.replace(self, **kw)
+
+
+_SECTIONS = {"moe": MoEConfig, "mla": MLAConfig, "ssm": SSMConfig,
+             "rwkv": RWKVConfig, "encdec": EncDecConfig,
+             "vision": VisionConfig}
 
 
 @dataclass(frozen=True)

@@ -20,8 +20,7 @@ def config() -> ModelConfig:
         d_ff=18432,                      # dense-FFN layers (first 3)
         vocab_size=129280,
         moe=MoEConfig(n_experts=256, top_k=8, d_expert=2048,
-                      n_shared=1, d_shared=2048, first_dense_layers=3,
-                      capacity_factor=1.25),
+                      n_shared=1, d_shared=2048, first_dense_layers=3),
         mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512,
                       qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
         mtp_depth=1,

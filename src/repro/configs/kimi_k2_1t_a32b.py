@@ -22,8 +22,7 @@ def config() -> ModelConfig:
         d_ff=18432,                      # dense-FFN first layer
         vocab_size=163840,
         moe=MoEConfig(n_experts=384, top_k=8, d_expert=2048,
-                      n_shared=1, d_shared=2048, first_dense_layers=1,
-                      capacity_factor=1.25),
+                      n_shared=1, d_shared=2048, first_dense_layers=1),
         rope_theta=50000.0,
         source="arXiv:2501.kimi2 (assignment table)",
     )

@@ -10,6 +10,7 @@ from repro.configs.base import ModelConfig
 _MODULES: Dict[str, str] = {
     "hymba-1.5b": "hymba_1_5b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "llama-3.2-vision-11b": "llama_3_2_vision_11b",
     "whisper-medium": "whisper_medium",

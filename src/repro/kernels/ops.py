@@ -7,11 +7,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import custom_batching
 
 from repro.core.compression import Compressed, k_for_ratio
 from repro.core.strategies import CODEC_LEVELS, quantization_scale
 from repro.kernels.block_topk import ROWS_TILE, block_topk_pallas
 from repro.kernels.ef_update import ef_update_pallas
+from repro.kernels.expert_gmm import expert_gmm_pallas, expert_tgmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_merge import fused_merge_pallas
 from repro.kernels.fused_merge import TILE_N as MERGE_TILE
@@ -178,3 +180,95 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                  blk_k=blk_k, interpret=_interpret())
     out = out[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     return out
+
+
+def _client_starts(group_sizes: jax.Array, m: int) -> jax.Array:
+    """[C * G] first row of each client's groups, clients' rows stacked in
+    blocks of ``m``."""
+    c = group_sizes.shape[0]
+    starts = (jnp.arange(c, dtype=jnp.int32)[:, None] * m
+              + jnp.cumsum(group_sizes, axis=1) - group_sizes)
+    return starts.reshape(-1)
+
+
+def _clients_gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                 transpose_rhs: bool) -> jax.Array:
+    """x [C, m, k] (each client's rows sorted by group), group_sizes [C, G],
+    w [G, k, n] shared or [C, G, k, n] per client -> [C, m, n], zero in the
+    rows past each client's last group: one kernel call over all clients."""
+    c, m, k = x.shape
+    if w.ndim == 4:
+        w = w.reshape((-1,) + w.shape[2:])
+    out = expert_gmm_pallas(x.reshape(c * m, k), w,
+                            _client_starts(group_sizes, m),
+                            group_sizes.reshape(-1),
+                            transpose_rhs=transpose_rhs,
+                            interpret=_interpret())
+    out = out.reshape(c, m, -1)
+    keep = jnp.arange(m) < jnp.sum(group_sizes, axis=1, keepdims=True)
+    return jnp.where(keep[..., None], out, jnp.zeros((), out.dtype))
+
+
+def _clients_tgmm(x: jax.Array, dy: jax.Array,
+                  group_sizes: jax.Array) -> jax.Array:
+    """x [C, m, k], dy [C, m, n], group_sizes [C, G] -> [C, G, k, n]."""
+    c, m, k = x.shape
+    out = expert_tgmm_pallas(x.reshape(c * m, k), dy.reshape(c * m, -1),
+                             _client_starts(group_sizes, m),
+                             group_sizes.reshape(-1), interpret=_interpret())
+    return out.reshape(group_sizes.shape + out.shape[1:])
+
+
+def _per_client(fn, batched_w: bool):
+    """``fn`` on one client's operands, and its rule under ``vmap``: the
+    clients' rows go through one kernel call (FL trains the cohort under a
+    vmap whose members have their own group sizes; the weights are shared
+    in a round's first local step and per client after it)."""
+    @custom_batching.custom_vmap
+    def one(x, w, group_sizes):
+        return fn(x[None], w, group_sizes[None])[0]
+
+    @one.def_vmap
+    def rule(axis_size, in_batched, x, w, group_sizes):
+        x_b, w_b, gs_b = in_batched
+        if not x_b:
+            x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+        if not gs_b:
+            group_sizes = jnp.broadcast_to(group_sizes,
+                                           (axis_size,) + group_sizes.shape)
+        if batched_w and not w_b:
+            w = jnp.broadcast_to(w, (axis_size,) + w.shape)
+        return fn(x, w, group_sizes), True
+
+    return one
+
+
+_gmm = _per_client(functools.partial(_clients_gmm, transpose_rhs=False),
+                   batched_w=False)
+_gmm_t = _per_client(functools.partial(_clients_gmm, transpose_rhs=True),
+                     batched_w=False)
+_tgmm = _per_client(_clients_tgmm, batched_w=True)
+
+
+@jax.custom_vjp
+def expert_matmul(x: jax.Array, w: jax.Array,
+                  group_sizes: jax.Array) -> jax.Array:
+    """Rows sorted by expert [m, k] x the experts' weights [G, k, n] ->
+    [m, n]: ``x[rows of g] @ w[g]`` for each group, zero in the rows past
+    the last group. ``group_sizes`` [G] int32. Differentiable in ``x`` and
+    ``w`` (``expert_gmm`` for the rows' gradient, ``expert_tgmm`` for the
+    weights'); under ``vmap`` the batch's rows go through one kernel call
+    per product."""
+    return _gmm(x, w, group_sizes)
+
+
+def _expert_matmul_fwd(x, w, group_sizes):
+    return _gmm(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _expert_matmul_bwd(res, dy):
+    x, w, group_sizes = res
+    return _gmm_t(dy, w, group_sizes), _tgmm(x, dy, group_sizes), None
+
+
+expert_matmul.defvjp(_expert_matmul_fwd, _expert_matmul_bwd)

@@ -82,17 +82,20 @@ def _attend_block(q, k, v, mask, scale):
 
 
 def attend(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-           q_offset: int = 0, chunk: int = 512) -> jax.Array:
+           q_offset: int = 0, chunk: int = 512,
+           scale: Optional[float] = None) -> jax.Array:
     """Full attention, q-chunked when Sq > chunk to bound score memory.
 
     q: [B,Sq,H,D]; k,v: [B,Sk,Hkv,D]. FLOP count equals the unmasked product
     (causal masking does not reduce compiled FLOPs — standard for TPU).
+    ``scale`` multiplies the scores (default D^-0.5).
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if sk >= 16384:  # long-context prefill: smaller q-chunks bound the
         chunk = min(chunk, 256)  # [B,H,chunk,Sk] score tiles
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q_pos_all = q_offset + jnp.arange(sq)
     k_pos = jnp.arange(sk)
     if sq <= chunk:

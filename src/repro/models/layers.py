@@ -10,6 +10,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _dtype(name: str):
@@ -94,11 +95,43 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 m ln(s) + 1`` (1 for s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(dim: int, base: float, original_max: int,
+                          beta_fast: float, beta_slow: float):
+    """(low, high): the rotary pairs below ``low`` keep their frequency,
+    those from ``high`` on are divided by the factor (YaRN's ramp ends)."""
+    def pair(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary inverse frequencies [dim/2] (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): a linear ramp over the pairs
+    ``low..high`` blends the plain frequencies into those divided by
+    ``factor``."""
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(dim, base, original_max, beta_fast,
+                                      beta_slow)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (extra / factor * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: Optional[np.ndarray] = None) -> jax.Array:
     """Half-rotation RoPE. x: [..., S, H, D] or [..., H, D]; positions
-    broadcastable to the S axis (or scalar for single-token decode)."""
+    broadcastable to the S axis (or scalar for single-token decode);
+    ``inv_freq`` [D/2] in place of the plain ``rope_freqs(D, theta)``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [d/2]
+    freqs = rope_freqs(d, theta) if inv_freq is None else \
+        jnp.asarray(inv_freq)                          # [d/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, d/2]
     if x.ndim == angles.ndim + 2:                      # add head axis
         angles = angles[..., None, :]

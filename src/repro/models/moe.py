@@ -1,14 +1,23 @@
-"""Routed MoE with capacity-based, *data-shard-local* dispatch.
+"""Routed MoE without dropped tokens, over the experts held on this chip.
 
-TPU adaptation: tokens are reshaped to [n_shards, T_loc, d] with the leading
-axis sharded over the batch mesh axes, and ALL routing (top-k, position
-cumsum, scatter into the [E, C_loc, d] dispatch buffer) happens per shard
-under vmap — no cross-shard sequentialization, no giant global scatter (the
-naive global formulation replicates [T·k, d] f32 buffers per device; see
-EXPERIMENTS.md §Perf). Expert FFNs run as one batched einsum with experts
-sharded over ``model`` (EP); capacity is enforced per shard (GShard-style
-local capacity). Shared experts are a plain dense branch. Aux load-balance
-loss follows Switch/GShard.
+The router scores every token against all ``n_experts`` routed experts
+(float32 softmax, greedy top-k; the gates renormalised only where
+``norm_topk_prob``, then times ``routed_scaling_factor``). This chip holds
+the first ``held`` experts (``MoEConfig.n_held``): a token's routed output
+here is ``sum over e in topk(t), e held: g_e(t) * FFN_e(x_t)``. What the
+experts held elsewhere add is not computed, and nothing stands in for it.
+
+Dispatch keeps every routed slot: the (token, slot) pairs routed to held
+experts are sorted by expert, gathered into rows, and run through the
+grouped matmuls (``kernels/expert_gmm``: gate and up as one, then down);
+each slot reads its row back, weighted by its gate. The row buffer holds
+``tokens * min(top_k, held)`` rows, the most the held experts can
+receive, so no capacity ever drops a slot; the kernels, the gather and
+its gradient touch only the rows in groups. Shared experts are one dense SwiGLU computed for every token. The
+layer holds no cross-token state beyond its input, so it batches under the
+FL trainer's ``vmap`` over clients, whose group sizes differ.
+
+The aux load-balance loss follows Switch/GShard.
 """
 from __future__ import annotations
 
@@ -17,19 +26,18 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist import sharding as shd
-from repro.models import flags
-from repro.dist.sharding import constrain
+from repro.kernels.ops import expert_matmul
 from repro.models.layers import dense_init
 
 
 def init_moe(key, d_model: int, mo, dtype):
     ks = jax.random.split(key, 8)
+    held = mo.held
     p = {
         "router": dense_init(ks[0], d_model, (d_model, mo.n_experts), jnp.float32),
-        "w_gate": dense_init(ks[1], d_model, (mo.n_experts, d_model, mo.d_expert), dtype),
-        "w_up": dense_init(ks[2], d_model, (mo.n_experts, d_model, mo.d_expert), dtype),
-        "w_down": dense_init(ks[3], mo.d_expert, (mo.n_experts, mo.d_expert, d_model), dtype),
+        "w_gate": dense_init(ks[1], d_model, (held, d_model, mo.d_expert), dtype),
+        "w_up": dense_init(ks[2], d_model, (held, d_model, mo.d_expert), dtype),
+        "w_down": dense_init(ks[3], mo.d_expert, (held, mo.d_expert, d_model), dtype),
     }
     if mo.n_shared:
         ds = (mo.d_shared or mo.d_expert) * mo.n_shared
@@ -41,104 +49,79 @@ def init_moe(key, d_model: int, mo, dtype):
     return p
 
 
-def _dispatch_positions(expert_idx: jax.Array, n_experts: int, capacity: int):
-    """expert_idx: [T, k] -> (positions [T, k], keep [T, k]).
-
-    Slot-sequential running count: for each of the k routing slots, a [T, E]
-    one-hot cumsum assigns intra-expert positions; a carried per-expert base
-    count links the slots. Peak temp is [T, E] i32 (not [T*k, E])."""
-    t, k = expert_idx.shape
-
-    def body(counts, idx_col):
-        onehot = jax.nn.one_hot(idx_col, n_experts, dtype=jnp.int32)  # [T, E]
-        ranks = jnp.cumsum(onehot, axis=0) - 1                        # 0-based
-        pos = jnp.take_along_axis(ranks, idx_col[:, None], axis=1)[:, 0] + \
-            counts[idx_col]
-        new_counts = counts + jnp.sum(onehot, axis=0)
-        return new_counts, pos
-
-    counts0 = jnp.zeros((n_experts,), jnp.int32)
-    _, pos = jax.lax.scan(body, counts0, expert_idx.T,
-                          unroll=flags.scan_unroll(k))
-    pos = pos.T                                                       # [T, k]
-    keep = pos < capacity
-    return pos, keep
-
-
-def _local_moe(p, xt: jax.Array, mo, act: str, capacity: int):
-    """One shard's routing + dispatch. xt: [T_loc, d] ->
-    (disp [E, C, d], combine [T_loc, k], ei [T_loc, k], pi [T_loc, k], aux)."""
-    tl, d = xt.shape
-    logits = (xt.astype(jnp.float32) @ p["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                           # [T, E]
-    gate_vals, expert_idx = jax.lax.top_k(probs, mo.top_k)            # [T, k]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-    pos, keep = _dispatch_positions(expert_idx, mo.n_experts, capacity)
-
-    disp = jnp.zeros((mo.n_experts, capacity, d), xt.dtype)
-    ei = expert_idx.reshape(-1)
-    pi = jnp.where(keep, pos, capacity - 1).reshape(-1)
-    xr = jnp.repeat(xt[:, None, :], mo.top_k, axis=1).reshape(-1, d)
-    xr = xr * keep.reshape(-1, 1).astype(xt.dtype)
-    disp = disp.at[ei, pi].add(xr)
-
-    combine = (gate_vals * keep).astype(xt.dtype)                     # [T, k]
+def route(router: jax.Array, xt: jax.Array, mo):
+    """xt [T, d] -> (gates [T, k] f32, experts [T, k] i32, aux loss)."""
+    logits = jnp.matmul(xt.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)                       # [T, E]
+    gates, experts = jax.lax.top_k(probs, mo.top_k)               # [T, k]
+    if mo.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    gates = gates * mo.routed_scaling_factor
     me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(expert_idx[:, 0], mo.n_experts,
+    ce = jnp.mean(jax.nn.one_hot(experts[:, 0], mo.n_experts,
                                  dtype=jnp.float32), axis=0)
-    aux = mo.n_experts * jnp.sum(me * ce)
-    return disp, combine, expert_idx, jnp.where(keep, pos, capacity - 1), aux
+    return gates, experts, mo.n_experts * jnp.sum(me * ce)
+
+
+def _ffn(rows, p, group_sizes, act: str):
+    """The held experts' FFN on rows sorted by expert: SwiGLU's gate and up
+    projections as one grouped matmul over their concatenated weights."""
+    if act == "swiglu":
+        f = p["w_up"].shape[-1]
+        gu = expert_matmul(rows, jnp.concatenate([p["w_gate"], p["w_up"]],
+                                                 axis=-1), group_sizes)
+        h = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+    else:
+        h = jax.nn.gelu(expert_matmul(rows, p["w_up"], group_sizes))
+    return expert_matmul(h, p["w_down"], group_sizes)
 
 
 def apply_moe(p, x: jax.Array, *, mo, act: str = "swiglu"
               ) -> Tuple[jax.Array, jax.Array]:
     """x: [B, S, d] -> (out [B, S, d], aux_loss scalar)."""
     b, s, d = x.shape
-    t = b * s
-    rules = shd.get_rules()
-    n_shards = 1
-    if rules is not None and rules.shard_batch:
-        n_shards = rules.batch_size()
-        if t % n_shards or t // n_shards < mo.top_k:
-            n_shards = 1
-    tl = t // n_shards
-    capacity = max(int(mo.capacity_factor * tl * mo.top_k / mo.n_experts),
-                   mo.top_k)
+    t, k, held = b * s, mo.top_k, mo.held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.route"):
+        gates, experts, aux = route(p["router"], xt, mo)
 
-    xt = x.reshape(n_shards, tl, d)
-    xt = constrain(xt, ("batch", None, None))
-    disp, combine, ei, pi, aux = jax.vmap(
-        lambda xs: _local_moe(p, xs, mo, act, capacity))(xt)
-    # disp: [n_shards, E, C, d] — data-sharded on dim0, EP on dim1
-    disp = constrain(disp, ("batch", "experts", None, None))
+    with jax.named_scope("moe.dispatch"):
+        slot_expert = experts.reshape(-1)                         # [T*k]
+        # slots routed elsewhere sort last, as group ``held``
+        key = jnp.minimum(slot_expert, held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                              dtype=jnp.int32)
+        n_rows = t * min(k, held)           # the most the held experts get
+        rows_cap = -(-n_rows // 8) * 8
+        # rows past the last group gather nothing: their source is out of
+        # range, so they read zeros and their gradient is dropped
+        src = jnp.where(jnp.arange(n_rows) < jnp.sum(group_sizes),
+                        order[:n_rows] // k, t)
+        src = jnp.pad(src, (0, rows_cap - n_rows), constant_values=t)
+        rows = jnp.take(xt, src, axis=0, mode="fill", fill_value=0)  # [R, d]
 
-    pet = dict(preferred_element_type=x.dtype) if x.dtype == jnp.bfloat16 \
-        else {}
-    up = jnp.einsum("secd,edf->secf", disp, p["w_up"], **pet)
-    if act == "swiglu":
-        h = jax.nn.silu(jnp.einsum("secd,edf->secf", disp, p["w_gate"],
-                                   **pet)) * up
-    else:
-        h = jax.nn.gelu(up)
-    y = jnp.einsum("secf,efd->secd", h, p["w_down"], **pet)  # [S, E, C, d]
-    y = constrain(y, ("batch", "experts", None, None))
+    with jax.named_scope("moe.experts"):
+        y = _ffn(rows, p, group_sizes, act)                       # [R, d]
 
-    # gather back per shard and combine with gates
-    def gather_shard(ys, eis, pis, cs):
-        yk = ys[eis.reshape(-1), pis.reshape(-1)].reshape(tl, mo.top_k, d)
-        return jnp.einsum("tkd,tk->td", yk, cs)
-
-    out = jax.vmap(gather_shard)(y, ei, pi, combine)     # [S, T_loc, d]
-    out = constrain(out, ("batch", None, None)).reshape(b, s, d)
+    with jax.named_scope("moe.combine"):
+        # each slot reads its own row back: a held slot its expert's
+        # output, any other a row past the last group (zero) or none
+        pos = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+        w = jnp.where(experts < held, gates, 0.0)
+        out = sum(jnp.take(y, pos[:, j], axis=0, mode="fill", fill_value=0)
+                  .astype(jnp.float32) * w[:, j, None]
+                  for j in range(k)).astype(x.dtype)
 
     if "shared" in p:
         sh = p["shared"]
-        xt2 = x.reshape(t, d)
-        su = xt2 @ sh["w_up"]
+        su = xt @ sh["w_up"]
         if act == "swiglu":
-            hh = jax.nn.silu(xt2 @ sh["w_gate"]) * su
+            hh = jax.nn.silu(xt @ sh["w_gate"]) * su
         else:
             hh = jax.nn.gelu(su)
-        out = out + (hh @ sh["w_down"]).reshape(b, s, d)
+        out = out + hh @ sh["w_down"]
 
-    return out, jnp.mean(aux)
+    return out.reshape(b, s, d), aux

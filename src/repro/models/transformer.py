@@ -91,8 +91,9 @@ class Model:
             nd = cfg.moe.first_dense_layers
             if nd:
                 p["dense_layers"] = self._init_stack(next(keys), nd, self._init_dense_block)
-            p["moe_layers"] = self._init_stack(next(keys), cfg.n_layers - nd,
-                                               self._init_moe_block)
+            if cfg.n_layers > nd:
+                p["moe_layers"] = self._init_stack(
+                    next(keys), cfg.n_layers - nd, self._init_moe_block)
             if cfg.mtp_depth:
                 p["mtp"] = {
                     "proj": dense_init(next(keys), 2 * d, (2 * d, d), self.dtype),
@@ -196,7 +197,8 @@ class Model:
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
         if cfg.mla is not None:
             a = mla.apply_mla(p["attn"], h, n_heads=cfg.n_heads, m=cfg.mla,
-                              theta=cfg.rope_theta, positions=positions, chunk=chunk)
+                              theta=cfg.rope_theta, positions=positions,
+                              eps=cfg.norm_eps, chunk=chunk)
         else:
             a = attn.self_attention(p["attn"], h, cfg=cfg, positions=positions,
                                     causal=True, window=window, chunk=chunk)
@@ -267,6 +269,8 @@ class Model:
                 for i in range(nd):
                     x = body(x, jax.tree.map(lambda a: a[i],
                                              params["dense_layers"]))
+            if "moe_layers" not in params:
+                return x, aux0
             body2 = _remat(lambda h, p: self._block_fwd(p, h, positions, None), remat)
 
             def moe_step(carry, p):
@@ -543,7 +547,8 @@ class Model:
                 p, c = xs
                 hh = rms_norm(h, p["ln1"], cfg.norm_eps)
                 a, c = mla.decode_mla(p["attn"], hh, c, pos, n_heads=cfg.n_heads,
-                                      m=cfg.mla, theta=cfg.rope_theta)
+                                      m=cfg.mla, theta=cfg.rope_theta,
+                                      eps=cfg.norm_eps)
                 h = h + a
                 h2 = rms_norm(h, p["ln2"], cfg.norm_eps)
                 if use_moe:

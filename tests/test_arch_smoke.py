@@ -102,6 +102,7 @@ def test_full_config_param_counts():
     """Full-size analytic counts are in the published ballpark."""
     expect = {
         "deepseek-v3-671b": (600e9, 700e9),
+        "deepseek-v2-lite": (14e9, 17e9),
         "kimi-k2-1t-a32b": (950e9, 1150e9),
         "qwen2.5-32b": (28e9, 36e9),
         "qwen2.5-14b": (13e9, 16e9),

@@ -113,19 +113,23 @@ def test_round_kernels_are_named_in_the_v5e_program(one_chip, monkeypatch):
             assert "fl.merge" in parts and kernel in parts, (n, op)
 
 
-def _compile_round_for(one_chip):
-    """The HLO of a one-round fl_train scan chunk of a tiny model, compiled
-    for the described chip with the Pallas kernels on the merge path, and
-    the model's number of parameter leaves."""
+def _compile_round_for(one_chip, arch="stablelm-1.6b"):
+    """The HLO of a one-round fl_train scan chunk of a tiny model of
+    ``arch``, compiled for the described chip with the Pallas kernels on the
+    merge path, and the model's number of parameter leaves."""
     import dataclasses
 
     from repro.configs import get_config
     from repro.fed import engine
     from repro.models import Model
 
-    cfg = dataclasses.replace(
-        get_config("stablelm-1.6b").reduced(), n_layers=1, d_model=32,
-        n_heads=2, n_kv_heads=2, head_dim=16, d_ff=64, vocab_size=256)
+    cfg = get_config(arch).reduced()
+    if arch == "stablelm-1.6b":
+        cfg = dataclasses.replace(cfg, n_layers=1, d_model=32, n_heads=2,
+                                  n_kv_heads=2, head_dim=16, d_ff=64,
+                                  vocab_size=256)
+    else:
+        cfg = dataclasses.replace(cfg, dtype="bfloat16")
     model = Model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     place = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
@@ -142,3 +146,74 @@ def _compile_round_for(one_chip):
                                     strategy="bcrs_opwa", use_kernel=True)
     hlo = sim.compile(params, place((0,), jnp.float32), xs).as_text()
     return hlo, len(jax.tree.leaves(shapes))
+
+
+#: the deepseek-v2-lite.silo cell's expert layer: each of 4 clients'
+#: dispatch buffer (batch 2 x seq 2048 tokens x top 6 rows) against the 8
+#: held experts' fused gate-up [2048, 2 x 1408] and down [1408, 2048]
+EXPERT_CLIENTS, EXPERT_ROWS, HELD = 4, 2 * 2048 * 6, 8
+EXPERT_CASES = {"gate_up": (2048, 2 * 1408), "down": (1408, 2048)}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("case", list(EXPERT_CASES))
+def test_expert_gmm_compiles_for_v5e(case, grad, one_chip, monkeypatch):
+    """The grouped matmuls as the cohort's vmapped local step runs them:
+    the forward product, or its backward (``expert_gmm`` for the rows'
+    gradient, ``expert_tgmm`` for the weights'), one Mosaic call each."""
+    import re
+
+    from repro.kernels import ops as kops
+
+    k, n = EXPERT_CASES[case]
+    cohort = jax.vmap(kops.expert_matmul, in_axes=(0, None, 0))
+
+    def step(x, w, group_sizes, dy):
+        if not grad:
+            return cohort(x, w, group_sizes)
+        _, vjp = jax.vjp(lambda x, w: cohort(x, w, group_sizes), x, w)
+        return vjp(dy)
+
+    c, m = EXPERT_CLIENTS, EXPERT_ROWS
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((c, m, k), jnp.bfloat16), ((HELD, k, n), jnp.bfloat16),
+        ((c, HELD), jnp.int32), ((c, m, n), jnp.bfloat16))]
+    jax.clear_caches()
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    try:
+        hlo = jax.jit(step).lower(*args).compile().as_text()
+    finally:
+        jax.clear_caches()
+    calls = re.findall(r"%[\w.\-]*?(expert_t?gmm)[\w.\-]* = .*tpu_custom_call",
+                       hlo)
+    want = ["expert_gmm", "expert_tgmm"] if grad else ["expert_gmm"]
+    assert sorted(calls) == want
+
+
+def test_expert_kernels_are_named_in_the_v5e_program(one_chip, monkeypatch):
+    """In a deepseek-v2-lite fl_train scan chunk compiled for the chip, the
+    grouped matmuls of every local step are Mosaic calls named after their
+    kernels, as the benchmark's ``expert_gmm_s`` reads them, under the
+    round's local-training scope and the expert layer's."""
+    import re
+
+    from repro.kernels import ops as kops
+
+    jax.clear_caches()
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    try:
+        hlo, _ = _compile_round_for(one_chip, "deepseek-v2-lite")
+    finally:
+        jax.clear_caches()
+    calls = [re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = .*op_name=\"([^\"]*)\"",
+                      line).groups()
+             for line in hlo.splitlines()
+             if "tpu_custom_call" in line and "expert_" in line]
+    names = sorted(re.fullmatch(r"(expert_t?gmm)\.\d+", n).group(1)
+                   for n, _ in calls)
+    # one MoE layer, no remat: gate-up and down forward and their rows'
+    # gradients; the two weight gradients
+    assert names == ["expert_gmm"] * 4 + ["expert_tgmm"] * 2
+    for n, op in calls:
+        parts = op.split("/")
+        assert "fl.local_train" in parts and "moe.experts" in parts, (n, op)
