@@ -5,8 +5,8 @@ Given the per-client k-th-magnitude thresholds from ``threshold_find``, the
 unfused XLA path still makes 4-6 more full passes over the [C, n] update
 matrix: EF correction, mask materialization, masked values, overlap counts,
 the coefficient-weighted sum, and the OPWA multiply each round-trip HBM.
-This kernel reads each (updates, residuals) tile once and produces, per
-n-tile and entirely in VMEM:
+This kernel reads each (updates, residuals) lane once and produces, per
+chunk of lanes and entirely in VMEM:
 
     corrected = residuals + updates          (EF configs)
     mask      = bitcast(|corrected|) >= threshold   (ties kept)
@@ -17,25 +17,44 @@ n-tile and entirely in VMEM:
     agg       = M . sum_c w_c * send         (coefficient-weighted merge)
     residual' = corrected - send             (inactive rows pass through)
 
-writing only the aggregate tile [1, T] (plus the residual tile for EF
-configs) back to HBM. It generalizes and subsumes the three static-k kernels
+writing only the aggregate [1, n] (plus the residuals for EF configs)
+back to HBM. It generalizes and subsumes the three static-k kernels
 (``block_topk``'s selection, ``ef_update``'s EF arithmetic,
 ``overlap_combine``'s merge) at traced per-client k.
 
-The codec stage (``codec="int8"|"int4"``) quantizes the send tile onto the
+The codec stage (``codec="int8"|"int4"``) quantizes the send values onto the
 symmetric integer grid with the per-client ``scales`` column (derived from
 ``threshold_find``'s row absmax — for Top-K the survivors' absmax equals
 the row absmax, so it costs no extra pass) and merges the DEQUANTIZED
 values; ``residual' = corrected - dequant(send)`` makes EF absorb the
 quantization error. The quantize->dequantize op sequence is
 ``core.strategies.symmetric_dequantize`` — literally the same function the
-jnp ``value_codec`` path runs — so the two routes are bit-exact per tile
+jnp ``value_codec`` path runs — so the two routes are bit-exact per lane
 (docs/DESIGN.md §10).
+
+Grid and blocks. The grid is ``(cdiv(n, width),)``: each grid step streams
+a ``(C, width)`` block of the updates (and of the residuals) through VMEM
+and writes the ``(1, width)`` aggregate block (and the ``(C, width)``
+residual block). ``block_lanes`` derives ``width`` from the call's shapes
+alone (C, n, and whether EF residuals are a second input): about
+``BLOCK_BYTES`` of input per grid step, so a round of a 1.03e9-parameter
+model at C=4 pays the fixed cost of a grid step ~7.8 K times; double
+buffered, inputs and outputs stay inside Mosaic's default scoped VMEM. A
+leaf smaller than one block takes a single block. Inside a block, a loop
+walks ``TILE_N`` = 1024 lanes an iteration as ``UNROLL`` ``(C, CHUNK)``
+slices; each slice runs the sequence above on its lanes and stores its
+slice of the outputs, so no block-sized intermediate is materialized.
+
+Padding and overhang. The wrapper zero-pads n to a multiple of
+``TILE_N``, so every block holds whole loop iterations. The last block
+may overhang the padded operand; its loop stops at the operand's end, so
+the undefined lanes a TPU loads past it are never read, and the output
+lanes past it are never written (nor copied back).
 
 Bit-exactness contract (asserted in tests/test_megakernel.py on the CPU and
 by chip_smoke.py on a TPU): every intermediate uses the same op sequence as
 the jnp reference in ``fed.engine.aggregate_updates`` — so agg and residuals
-match the traced jnp path bit for bit, per-tile, including the all-True tie
+match the traced jnp path bit for bit, per lane, including the all-True tie
 masks of all-zero rows. The weighted sum is an f32 multiply and a sum over
 the client axis: XLA on a TPU lowers the reference's ``einsum("k,kn->n")``
 (and the per-leaf ``tensordot``, at any matmul precision) to exactly that,
@@ -59,11 +78,30 @@ from jax.experimental import pallas as pl
 # (strategies imports only jnp — no cycle)
 from repro.core.strategies import CODEC_LEVELS, symmetric_dequantize
 
+SUBLANES = 8
+#: the operand's width is padded to a multiple of one loop iteration's lanes
 TILE_N = 1024
+#: lanes of the (C, CHUNK) slice each step of the in-block loop computes;
+#: UNROLL of them make one iteration of TILE_N lanes. One vreg column per
+#: slice ran fastest on a v5e (256, 512 and 1024 lanes all ran slower)
+CHUNK = 128
+UNROLL = TILE_N // CHUNK
+#: input bytes streamed per grid step (rows rounded up to whole vregs)
+BLOCK_BYTES = 4 << 20
+
+
+def block_lanes(c: int, n: int, ef: bool) -> int:
+    """Lanes of the ``(C, width)`` block each grid step streams: about
+    ``BLOCK_BYTES`` of input, a multiple of ``TILE_N``, and no wider than
+    the operand rounded up to ``TILE_N``."""
+    rows = -(-c // SUBLANES) * SUBLANES
+    width = BLOCK_BYTES // ((2 if ef else 1) * rows * 4) // TILE_N * TILE_N
+    return min(max(width, TILE_N), -(-n // TILE_N) * TILE_N)
 
 
 def _fused_merge_kernel(ef: bool, opwa: bool, gamma: float, d: int,
-                        has_active: bool, codec: str, *refs):
+                        has_active: bool, codec: str, n: int, width: int,
+                        *refs):
     refs = list(refs)
     x_ref = refs.pop(0)
     e_ref = refs.pop(0) if ef else None
@@ -73,41 +111,65 @@ def _fused_merge_kernel(ef: bool, opwa: bool, gamma: float, d: int,
     act_ref = refs.pop(0) if has_active else None
     agg_ref = refs.pop(0)
     newres_ref = refs.pop(0) if ef else None
+    c = x_ref.shape[0]
 
-    x = x_ref[...].astype(jnp.float32)                      # [C, T]
-    corrected = e_ref[...].astype(jnp.float32) + x if ef else x
-    bits = jax.lax.bitcast_convert_type(jnp.abs(corrected), jnp.uint32)
-    mask = bits >= th_ref[...]                              # [C, T]
-    vals = jnp.where(mask, corrected, jnp.float32(0.0))
-    if codec != "none":
-        # the jnp codec's exact op sequence on the jnp codec's exact scale
-        # (absmax/levels, prefetched as a [C, 1] column) — survivors land on
-        # the integer grid, non-survivors stay exactly zero, all-zero rows
-        # keep scale 0 and dequantize to exact zeros
-        vals = symmetric_dequantize(vals, sc_ref[...], CODEC_LEVELS[codec])
+    # the [C, 1] columns, broadcast once per block to the chunk's shape
+    col = lambda ref: jnp.broadcast_to(ref[...], (c, CHUNK))  # noqa: E731
+    th, w = col(th_ref), col(w_ref)
+    sc = col(sc_ref) if codec != "none" else None
+    act = col(act_ref) if has_active else None
 
-    if ef:
-        new_res = corrected - vals
+    def chunk(off):
+        lanes = pl.ds(off, CHUNK)
+        x = x_ref[:, lanes].astype(jnp.float32)             # [C, CHUNK]
+        e = e_ref[:, lanes].astype(jnp.float32) if ef else None
+        corrected = e + x if ef else x
+        bits = jax.lax.bitcast_convert_type(jnp.abs(corrected), jnp.uint32)
+        mask = bits >= th
+        vals = jnp.where(mask, corrected, jnp.float32(0.0))
+        if codec != "none":
+            # the jnp codec's exact op sequence on the jnp codec's exact
+            # scale (absmax/levels, a [C, 1] column) — survivors land on
+            # the integer grid, non-survivors stay exactly zero, all-zero
+            # rows keep scale 0 and dequantize to exact zeros
+            vals = symmetric_dequantize(vals, sc, CODEC_LEVELS[codec])
+
+        if ef:
+            new_res = corrected - vals
+            if has_active:
+                new_res = jnp.where(act > jnp.float32(0.5), new_res, e)
+            newres_ref[:, lanes] = new_res
         if has_active:
-            act_b = act_ref[...] > jnp.float32(0.5)         # [C, 1]
-            new_res = jnp.where(act_b, new_res, e_ref[...])
-        newres_ref[...] = new_res
-    if has_active:
-        act_b = act_ref[...] > jnp.float32(0.5)
-        # padded rows are all-zero updates whose tie-at-zero Top-K mask is
-        # all-True — gate them out of the merge and the overlap counts
-        vals = vals * act_ref[...]
-        mask = mask & act_b
+            # padded rows are all-zero updates whose tie-at-zero Top-K mask
+            # is all-True — gate them out of the merge and the overlap
+            # counts
+            vals = vals * act
+            mask = mask & (act > jnp.float32(0.5))
 
-    # multiply and sum over clients, not a dot: see the module docstring
-    weighted = jnp.sum(w_ref[...] * vals, axis=0, keepdims=True)   # [1, T]
-    if opwa:
-        counts = jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
-        amplify = (counts > 0) & (counts <= d)
-        m = jnp.where(amplify, jnp.float32(gamma), jnp.float32(1.0))
-        agg_ref[...] = m * weighted
+        # multiply and sum over clients, not a dot: see the module docstring
+        weighted = jnp.sum(w * vals, axis=0, keepdims=True)  # [1, CHUNK]
+        if opwa:
+            counts = jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
+            amplify = (counts > 0) & (counts <= d)
+            m = jnp.where(amplify, jnp.float32(gamma), jnp.float32(1.0))
+            weighted = m * weighted
+        agg_ref[:, lanes] = weighted
+
+    def step(i, carry):
+        base = pl.multiple_of(i * TILE_N, TILE_N)
+        for u in range(UNROLL):
+            chunk(base + u * CHUNK)
+        return carry
+
+    # the last block may overhang n: its loop stops at n (module docstring)
+    full = width // TILE_N
+    last = (n - (-(-n // width) - 1) * width) // TILE_N
+    if last == full:
+        steps = full
     else:
-        agg_ref[...] = weighted
+        final = pl.program_id(0) == pl.num_programs(0) - 1
+        steps = jnp.where(final, last, full)
+    jax.lax.fori_loop(0, steps, step, 0)
 
 
 def fused_merge_pallas(x2d: jax.Array, thresholds: jax.Array,
@@ -152,13 +214,13 @@ def fused_merge_pallas(x2d: jax.Array, thresholds: jax.Array,
     np_ = n + n_pad
     ef = e2d is not None
     has_active = active is not None
-    grid = (np_ // TILE_N,)
-    tile = pl.BlockSpec((c, TILE_N), lambda t: (0, t))
+    width = block_lanes(c, np_, ef)
+    block = pl.BlockSpec((c, width), lambda t: (0, t))
     col = pl.BlockSpec((c, 1), lambda t: (0, 0))
 
-    in_specs, args = [tile], [x2d]
+    in_specs, args = [block], [x2d]
     if ef:
-        in_specs.append(tile)
+        in_specs.append(block)
         args.append(e2d)
     in_specs += [col, col]
     args += [thresholds, weights.astype(jnp.float32)]
@@ -169,16 +231,16 @@ def fused_merge_pallas(x2d: jax.Array, thresholds: jax.Array,
         in_specs.append(col)
         args.append(active.astype(jnp.float32))
 
-    out_specs = [pl.BlockSpec((1, TILE_N), lambda t: (0, t))]
+    out_specs = [pl.BlockSpec((1, width), lambda t: (0, t))]
     out_shape = [jax.ShapeDtypeStruct((1, np_), jnp.float32)]
     if ef:
-        out_specs.append(tile)
+        out_specs.append(block)
         out_shape.append(jax.ShapeDtypeStruct((c, np_), jnp.float32))
 
     out = pl.pallas_call(
         functools.partial(_fused_merge_kernel, ef, opwa, float(gamma),
-                          int(d), has_active, codec),
-        grid=grid,
+                          int(d), has_active, codec, np_, width),
+        grid=(pl.cdiv(np_, width),),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

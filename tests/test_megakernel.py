@@ -16,6 +16,7 @@ from repro.core import compression as C
 from repro.core.opwa import opwa_aggregate_traced_k
 from repro.fed import engine
 from repro.kernels import ops, ref
+from repro.kernels.fused_merge import block_lanes as merge_block_lanes
 from repro.kernels.fused_merge import fused_merge_pallas
 from repro.kernels.threshold_find import block_lanes, threshold_find_pallas
 
@@ -443,6 +444,49 @@ class TestFusedMergeRaggedWidth:
         np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(want[1]))
         np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want[0]),
                                    rtol=1e-6, atol=0)
+
+
+class TestFusedMergeTiling:
+    """The block/chunk tiling of fused_merge, bit for bit against the
+    oracle: widths over several blocks that end in a partial block and a
+    partial chunk, or inside one block; ties straddling the threshold in
+    every block, an all-zero row, and inactive padded rows."""
+
+    @pytest.mark.parametrize("mode", ["plain", "opwa", "ef", "ef_int8"])
+    @pytest.mark.parametrize("size", ["blocks", "leaf"])
+    @pytest.mark.parametrize("c", [1, 4, 9])
+    def test_vs_ref(self, c, size, mode):
+        ef = mode.startswith("ef")
+        w_block = merge_block_lanes(c, 1 << 40, ef)
+        n = (2 * w_block + w_block // 2 + 384 if size == "blocks"
+             else 3 * 1024 + 384)
+        assert (n > 2 * w_block) == (size == "blocks")
+        u, e, w, ks = _case(c, n, seed=c + n)
+        # 300 tied magnitudes spread over every block, with k inside them
+        ties = np.arange(300) * (n // 300)
+        u = u.at[0, ties].set(jnp.where(ties % 2 == 0, 5.0, -5.0))
+        e = e.at[0, ties].set(0.0)
+        ks = ks.at[0].set(150)
+        active = None
+        if c > 1:
+            u = u.at[1].set(0.0)                   # all-zero row
+            e = e.at[1].set(0.0)
+            if mode == "opwa":                     # a padded, inactive row
+                active = jnp.ones((c, 1)).at[c - 1].set(0.0)
+                u = u.at[c - 1].set(0.0)
+        res = e if ef else None
+        codec = "int8" if mode == "ef_int8" else "none"
+        scales = _codec_scales(e + u, codec) if codec != "none" else None
+        th = ref.threshold_find_ref(u, ks, res)
+        kw = dict(opwa=mode != "plain", gamma=4.0, d=2, codec=codec,
+                  scales=scales)
+        out = fused_merge_pallas(u, th, w.reshape(c, 1), res, active,
+                                 interpret=True, **kw)
+        want = ref.fused_merge_ref(u, th, w, res, active, **kw)
+        out, want = (out, want) if ef else ((out,), (want,))
+        for got, expect in zip(out, want):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(expect))
 
 
 class TestCodecScaleProvenance:

@@ -19,9 +19,9 @@ from repro.kernels.threshold_find import threshold_find_pallas
 
 #: 8 clients x the stablelm-1.6b MLP weight (d_model 2048 x d_ff 5632)
 C, N = 8, 2048 * 5632
-#: the merge cell's cohort: its largest leaf (the 100352 x 2048 embedding)
-#: and a norm-sized leaf (2048, already a multiple of megakernel_aggregate's
-#: 1024 padding)
+#: the merge cell's cohort: its largest leaf (the 100352 x 2048 embedding,
+#: many blocks of either kernel) and a norm-sized leaf (2048, one block,
+#: already a multiple of megakernel_aggregate's 1024 padding)
 C_MERGE, N_EMBED, N_NORM = 4, 100352 * 2048, 2048
 
 
@@ -69,6 +69,10 @@ CASES = {
     "threshold_find_ef_scale_norm": (_tf_ef_scale, ("x", "ks", "x"),
                                      (C_MERGE, N_NORM)),
     "fused_merge_opwa": (_fm_opwa, ("x", "th", "col", "col"), (C, N)),
+    "fused_merge_opwa_embedding": (_fm_opwa, ("x", "th", "col", "col"),
+                                   (C_MERGE, N_EMBED)),
+    "fused_merge_opwa_norm": (_fm_opwa, ("x", "th", "col", "col"),
+                              (C_MERGE, N_NORM)),
     "fused_merge_ef_int8": (_fm_ef_int8, ("x", "th", "col", "x", "col",
                                           "col"), (C, N)),
 }
