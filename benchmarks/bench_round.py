@@ -392,7 +392,7 @@ KERNEL_SCAN_STRATEGIES = ("bcrs_opwa", "qtopk")
 
 def bench_kernels_cell(strategy: str, clients: int, n: int,
                        iters: int) -> dict:
-    """One [C, n] merge through the unfused jnp ``aggregate_updates`` vs the
+    """One [C, n] merge through the unfused jnp ``compress_merge_leaf`` vs the
     traced-k Pallas megakernel pipeline: roofline HBM bytes (analytic DMA
     model vs trip-count-aware HLO accounting — see
     repro.roofline.kernel_bytes), wall-clock, and bit-exact parity.
@@ -412,7 +412,8 @@ def bench_kernels_cell(strategy: str, clients: int, n: int,
     # BCRS-style spread of per-client retained counts
     crs = np.geomspace(0.01, 0.5, clients)
     ks = jnp.asarray([k_for_ratio(n, float(c)) for c in crs], jnp.int32)
-    ef = strat_mod.get(strategy).needs_residuals
+    strat = strat_mod.get(strategy)
+    ef = strat.needs_residuals
 
     platform = jax.devices()[0].platform
     out = {"strategy": strategy, "clients": clients, "n": n,
@@ -422,11 +423,10 @@ def bench_kernels_cell(strategy: str, clients: int, n: int,
            "backend": platform, "interpret": platform != "tpu"}
     aggs = {}
     for label, use_kernel in (("unfused", False), ("kernel", True)):
-        spec = engine_mod.ClientUpdateSpec(strategy=strategy, gamma=5.0,
-                                           use_kernel=use_kernel)
-        fn = jax.jit(lambda u, w, ks, e, spec=spec: engine_mod.
-                     aggregate_updates(spec, u, w, ks,
-                                       residuals=e if ef else None))
+        fn = jax.jit(lambda u, w, ks, e, use_kernel=use_kernel: engine_mod.
+                     compress_merge_leaf(u, w, ks, strat, gamma=5.0,
+                                         overlap_d=1, use_kernel=use_kernel,
+                                         residuals=e if ef else None))
         agg, new_res = fn(u, w, ks, e)              # warmup/compile
         agg.block_until_ready()
         walls = []
@@ -446,8 +446,7 @@ def bench_kernels_cell(strategy: str, clients: int, n: int,
     out["bit_exact"] = bool(
         (aggs["kernel"][0] == aggs["unfused"][0]).all()
         and (not ef or (aggs["kernel"][1] == aggs["unfused"][1]).all()))
-    spec_ref = engine_mod.ClientUpdateSpec(strategy=strategy, gamma=5.0,
-                                           use_kernel=False)
+    spec_ref = engine_mod.ClientUpdateSpec(strategy=strategy, gamma=5.0)
     out["roofline"] = merge_traffic_ratio(spec_ref, clients, n)
     # upload pricing of the cell's median per-client k under the strategy's
     # registered wire format (packed codecs beat the idx32+f32 reference

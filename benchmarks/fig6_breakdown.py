@@ -1,6 +1,5 @@
 """Paper Fig. 6: per-round time breakdown — compress/decompress, training,
-uncompressed communication vs BCRS communication — plus kernel-path timing
-for the compression hot-spot (block_topk / overlap_combine wall time).
+uncompressed communication vs BCRS communication.
 """
 from __future__ import annotations
 
@@ -42,10 +41,9 @@ def run(verbose: bool = True):
                "y": jnp.asarray(rng.integers(0, 10, (8, 64)), jnp.int32)}
     t_train = _time(lambda: local(params, batches))
 
-    # compression time (jnp path vs Pallas interpret path)
+    # compression time
     u = jnp.asarray(rng.normal(0, 1, (n,)), jnp.float32)
     t_topk = _time(lambda: C.topk_compress(u, 0.01).values)
-    t_block = _time(lambda: C.block_topk_compress(u, 0.01, 4096).values)
 
     # communication: uncompressed vs uniform top-k vs BCRS
     t_dense = cost_model.uncompressed_round(links, v_bytes).actual
@@ -55,13 +53,12 @@ def run(verbose: bool = True):
 
     rows = {
         "train_s": t_train, "compress_topk_s": t_topk,
-        "compress_block_s": t_block, "comm_dense_s": t_dense,
+        "comm_dense_s": t_dense,
         "comm_topk_s": t_topk_comm, "comm_bcrs_s": t_bcrs_comm,
     }
     if verbose:
         print(f"fig6 train={t_train * 1e3:.1f}ms "
-              f"compress(topk)={t_topk * 1e3:.1f}ms "
-              f"compress(block)={t_block * 1e3:.1f}ms")
+              f"compress(topk)={t_topk * 1e3:.1f}ms")
         print(f"fig6 comm: dense={t_dense:.2f}s topk={t_topk_comm:.3f}s "
               f"bcrs={t_bcrs_comm:.3f}s "
               f"(bcrs == topk benchmark time, by construction)")

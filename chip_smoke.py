@@ -76,8 +76,8 @@ def kernel_parity() -> None:
         agg_k, res_k = kops.megakernel_aggregate(updates, ks, weights, res,
                                                  active, **kw)
         ref = jax.jit(functools.partial(
-            compress_merge_leaf, gamma=3.0, overlap_d=1,
-            opwa=strat.overlap_weighted, use_kernel=False))
+            compress_merge_leaf, strategy=strat, gamma=3.0, overlap_d=1,
+            use_kernel=False))
         agg_r, res_r = ref(updates.reshape((c,) + leaf), weights, ks,
                            residuals=(res.reshape((c,) + leaf)
                                       if res is not None else None),

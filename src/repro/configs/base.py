@@ -275,7 +275,6 @@ class RunConfig:
     opwa_gamma: float = 5.0
     opwa_overlap_threshold: int = 1
     server_lr: float = 1.0        # alpha
-    block_size: int = 8192        # block top-k block size
     # checkpointing
     checkpoint_dir: str = ""
     checkpoint_every: int = 50

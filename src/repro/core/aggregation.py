@@ -37,8 +37,6 @@ class AggregationConfig:
     alpha: float = 1.0             # server lr inside coefficients (Eq. 6)
     gamma: float = 5.0             # OPWA enlarge rate
     overlap_d: int = 1             # OPWA required degree of overlap
-    block_topk: bool = False       # use TPU block top-k instead of exact
-    block_size: int = 8192
     use_kernel: object = "auto"    # Pallas kernels: True | False | "auto"
 
     def __post_init__(self):
@@ -79,13 +77,11 @@ def round_schedule(acfg: AggregationConfig, k: int, data_fracs: np.ndarray,
     return sched.crs, sched.coefficients, info
 
 
-def ks_for_schedule(n: int, crs: np.ndarray, acfg: AggregationConfig
-                    ) -> np.ndarray:
+def ks_for_schedule(n: int, crs: np.ndarray) -> np.ndarray:
     """Per-client retained counts for the traced compressors. Computed on
-    host in f64 so they match the legacy per-client ``k_for_ratio`` exactly
-    (block mode: k per block of ``block_size``)."""
-    base = acfg.block_size if acfg.block_topk else n
-    return np.asarray([comp.k_for_ratio(base, float(c)) for c in crs],
+    host in f64 so they match the legacy per-client ``k_for_ratio``
+    exactly."""
+    return np.asarray([comp.k_for_ratio(n, float(c)) for c in crs],
                       np.int32)
 
 
@@ -104,17 +100,12 @@ def overlap_ks(acfg: AggregationConfig, info: dict, k: int, n: int
 
 # ------------------------------------------------------- client compression
 def _compress_fn(acfg: AggregationConfig):
-    if acfg.block_topk:
-        base = lambda u, cr: comp.block_topk_compress(
-            u, cr, block=acfg.block_size, use_kernel=acfg.use_kernel)
-    else:
-        base = comp.topk_compress
     codec = acfg.strat.value_codec
     if codec is None:
-        return base
+        return comp.topk_compress
 
     def fn(u, cr):
-        c = base(u, cr)
+        c = comp.topk_compress(u, cr)
         # the codec contract is batched ([C, ...] leading client axis);
         # single-client callers add/strip it here
         return comp.Compressed(codec(c.values[None], c.mask[None])[0],
@@ -123,10 +114,9 @@ def _compress_fn(acfg: AggregationConfig):
     return fn
 
 
-@functools.partial(jax.jit, static_argnames=("block", "codec"))
-def _compress_batch(updates, ks, residuals, block, codec=None):
-    fn = (comp.topk_compress_batch if block is None else
-          functools.partial(comp.block_topk_compress_batch, block=block))
+@functools.partial(jax.jit, static_argnames=("codec",))
+def _compress_batch(updates, ks, residuals, codec=None):
+    fn = comp.topk_compress_batch
     if codec is not None:
         base = fn
         fn = lambda u, k_: (lambda c: comp.Compressed(
@@ -147,17 +137,12 @@ def compress_clients(updates: jax.Array, crs: np.ndarray,
 
     One compiled program with *traced* per-client k — any BCRS schedule
     reuses the same executable (the legacy loop re-lowered ``lax.top_k``
-    per distinct static CR). Kernel-backed block top-k keeps the loop path
-    (the Pallas kernel wants a static k); everything else is vectorized.
-    A registered ``value_codec`` rides along as a static arg (module-level
-    functions hash stably, so the jit cache stays warm).
+    per distinct static CR). A registered ``value_codec`` rides along as a
+    static arg (module-level functions hash stably, so the jit cache stays
+    warm).
     """
-    if acfg.block_topk and comp.resolve_use_kernel(acfg.use_kernel):
-        return compress_clients_loop(updates, crs, acfg, residuals)
-    ks = jnp.asarray(ks_for_schedule(updates.shape[1], crs, acfg))
-    block = acfg.block_size if acfg.block_topk else None
-    return _compress_batch(updates, ks, residuals, block,
-                           acfg.strat.value_codec)
+    ks = jnp.asarray(ks_for_schedule(updates.shape[1], crs))
+    return _compress_batch(updates, ks, residuals, acfg.strat.value_codec)
 
 
 def compress_clients_loop(updates: jax.Array, crs: np.ndarray,
@@ -165,8 +150,7 @@ def compress_clients_loop(updates: jax.Array, crs: np.ndarray,
                           residuals: Optional[jax.Array] = None
                           ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """Legacy per-client loop (static-CR compressors). Kept as the parity
-    reference for the vectorized path and as the route to the static-k
-    Pallas block-top-k kernel."""
+    reference for the vectorized path."""
     fn = _compress_fn(acfg)
     vals, masks, new_res = [], [], []
     for i in range(updates.shape[0]):
@@ -210,8 +194,7 @@ def aggregate(updates: jax.Array, data_fracs: np.ndarray,
     vals, masks, new_res = compress(updates, crs, acfg, res)
     if strat.overlap_weighted:
         agg = opwa_mod.opwa_aggregate(vals, masks, coeffs, acfg.gamma,
-                                      acfg.overlap_d,
-                                      use_kernel=acfg.use_kernel)
+                                      acfg.overlap_d)
     else:
         agg = jnp.einsum("k,kn->n", coeffs, vals.astype(jnp.float32))
     return agg, info, new_res

@@ -1,5 +1,5 @@
-"""Update/gradient compressors: exact Top-K, block Top-K (TPU-native),
-Rand-K, stochastic quantization, and error-feedback wrappers.
+"""Update/gradient compressors: exact Top-K (static and traced k), Rand-K,
+stochastic quantization, and error-feedback wrappers.
 
 All compressors operate on flat f32/bf16 vectors; ``flatten_tree`` /
 ``unflatten_tree`` move between pytrees and vectors. The dense-masked
@@ -9,7 +9,6 @@ wire format whose byte count the cost model and the compressed pod-sync use.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, NamedTuple, Tuple
 
 import jax
@@ -72,30 +71,6 @@ def topk_compress(u: jax.Array, cr: float) -> Compressed:
     return Compressed(jnp.where(mask, u, 0), mask)
 
 
-def block_topk_compress(u: jax.Array, cr: float, block: int = 8192,
-                        use_kernel="auto") -> Compressed:
-    """Per-block magnitude Top-K (TPU adaptation; see docs/DESIGN.md §2).
-
-    Pads to a block multiple; each block keeps its own top ``cr`` fraction,
-    preserving the global compression ratio exactly while keeping selection
-    inside VMEM-sized tiles.
-    """
-    if resolve_use_kernel(use_kernel):
-        from repro.kernels import ops as kops
-        return kops.block_topk(u, cr, block=block)
-    n = u.shape[0]
-    n_pad = (-n) % block
-    up = jnp.pad(u, (0, n_pad))
-    nb = up.shape[0] // block
-    ub = up.reshape(nb, block)
-    k = k_for_ratio(block, cr)
-    mag = jnp.abs(ub.astype(jnp.float32))
-    thresh = jax.lax.top_k(mag, k)[0][:, -1:]
-    mask = mag >= thresh
-    vals = jnp.where(mask, ub, 0).reshape(-1)[:n]
-    return Compressed(vals, mask.reshape(-1)[:n])
-
-
 def topk_compress_dynamic(u: jax.Array, k: jax.Array,
                           n_iters: int = 32) -> Compressed:
     """Top-K with a *traced* k (per-client BCRS ratios under vmap).
@@ -131,67 +106,20 @@ def topk_compress_dynamic(u: jax.Array, k: jax.Array,
 
 
 # ------------------------------------------------- batched traced-k top-k
-def topk_compress_batch(updates: jax.Array, ks: jax.Array,
-                        use_kernel: bool = False) -> Compressed:
+def topk_compress_batch(updates: jax.Array, ks: jax.Array) -> Compressed:
     """Per-row dynamic Top-K: updates [K, n], ks int32 [K] (traced).
 
     One trace serves every BCRS schedule — the per-client ``float(cr)``
-    static-arg retrace this replaces cost O(rounds × K) XLA compiles.
-    ``use_kernel=True`` finds the thresholds through the Pallas
-    ``threshold_find`` kernel (8 streamed HBM sweeps instead of 32 unfused
-    bisection passes) and applies them in one more pass — bit-identical
-    masks/values, same traced-k contract."""
-    if use_kernel:
-        from repro.kernels import ops as kops
-        th = kops.topk_thresholds(updates, ks)
-        bits = jax.lax.bitcast_convert_type(
-            jnp.abs(updates.astype(jnp.float32)), jnp.uint32)
-        mask = bits >= th[:, None]
-        return Compressed(jnp.where(mask, updates, 0), mask)
+    static-arg retrace this replaces cost O(rounds × K) XLA compiles."""
     return jax.vmap(topk_compress_dynamic)(updates, ks)
-
-
-def block_topk_compress_batch(updates: jax.Array, ks_block: jax.Array,
-                              block: int = 8192) -> Compressed:
-    """Per-row *blockwise* dynamic Top-K: each client keeps its top
-    ``ks_block[i]`` entries per ``block``-sized tile (traced k)."""
-    c, n = updates.shape
-    n_pad = (-n) % block
-    ub = jnp.pad(updates, ((0, 0), (0, n_pad))).reshape(c, -1, block)
-    per_block = jax.vmap(lambda u, k: jax.vmap(
-        lambda b: topk_compress_dynamic(b, k))(u))
-    comp = per_block(ub, ks_block)
-    return Compressed(comp.values.reshape(c, -1)[:, :n],
-                      comp.mask.reshape(c, -1)[:, :n])
 
 
 def ef_compress_batch(residuals: jax.Array, updates: jax.Array,
                       ks: jax.Array,
-                      compress_batch: Callable = topk_compress_batch,
-                      use_kernel: bool = False
+                      compress_batch: Callable = topk_compress_batch
                       ) -> Tuple[Compressed, jax.Array]:
     """Batched EF-TopK: bit-compatible with a per-client ``ef_compress``
-    loop (same corrected/send/residual arithmetic, vectorized).
-    ``use_kernel=True`` (global Top-K only) selects on
-    ``residuals + updates`` through the Pallas threshold kernel without
-    materializing the corrected array once per bisection step; combining it
-    with a non-global ``compress_batch`` is a loud error, not a silent
-    semantic switch."""
-    if use_kernel:
-        if compress_batch is not topk_compress_batch:
-            raise ValueError(
-                "ef_compress_batch(use_kernel=True) implements global Top-K "
-                "selection only — it cannot honor a custom compress_batch "
-                f"({getattr(compress_batch, '__name__', compress_batch)}); "
-                "pass use_kernel=False for block/other compressors")
-        from repro.kernels import ops as kops
-        th = kops.topk_thresholds(updates, ks, residuals=residuals)
-        corrected = residuals + updates
-        bits = jax.lax.bitcast_convert_type(
-            jnp.abs(corrected.astype(jnp.float32)), jnp.uint32)
-        mask = bits >= th[:, None]
-        vals = jnp.where(mask, corrected, 0)
-        return Compressed(vals, mask), corrected - vals
+    loop (same corrected/send/residual arithmetic, vectorized)."""
     corrected = residuals + updates
     comp = compress_batch(corrected, ks)
     return comp, corrected - comp.values
@@ -245,9 +173,3 @@ def from_sparse(indices: jax.Array, values: jax.Array, n: int) -> jax.Array:
     safe_idx = jnp.where(indices >= 0, indices, 0)
     contrib = jnp.where(indices >= 0, values, 0)
     return jnp.zeros((n,), values.dtype).at[safe_idx].add(contrib)
-
-
-COMPRESSORS = {
-    "topk": topk_compress,
-    "blocktopk": block_topk_compress,
-}

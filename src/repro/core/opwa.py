@@ -7,17 +7,15 @@ aggregated update scaled by the enlarge rate gamma; everything else by 1.
 The server update (Alg. 1 line 18):
     w_{t+1} = w_t - eta * sum_i p'_i * M ⊙ Δw_i^sparse
 with M shared across clients, so aggregation fuses into a single masked
-weighted sum — exactly what the ``overlap_combine`` Pallas kernel computes in
-one HBM pass.
+weighted sum. ``opwa_aggregate`` is the jnp reference; the engines' kernel
+route computes the same merge inside ``kernels.fused_merge``.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.core.compression import resolve_use_kernel
 
 
 def overlap_counts(masks: jax.Array) -> jax.Array:
@@ -43,59 +41,19 @@ def overlap_histogram(masks: jax.Array, k_max: Optional[int] = None
 
 
 def opwa_aggregate(updates: jax.Array, masks: jax.Array, coeffs: jax.Array,
-                   gamma: float, d: int = 1,
-                   use_kernel="auto") -> jax.Array:
-    """Fused OPWA aggregation (rank-agnostic).
+                   gamma: float, d: int = 1) -> jax.Array:
+    """Fused OPWA aggregation (rank-agnostic, pure jnp).
 
     updates: [K, *shape] dense-masked sparse updates (flat [K, n] from the
     round engines, natural possibly-sharded leaf layout from the mesh/pod
     adapters); masks: matching bool; coeffs: [K] client coefficients p'_i.
-    Returns M ⊙ Σ_i p'_i u_i  [*shape]. The Pallas kernel route applies to
-    the flat [K, n] layout only.
+    Returns M ⊙ Σ_i p'_i u_i  [*shape].
     """
-    if resolve_use_kernel(use_kernel) and updates.ndim == 2:
-        from repro.kernels import ops as kops
-        return kops.overlap_combine(updates, masks, coeffs, gamma, d)
     counts = overlap_counts(masks)
     m = opwa_mask(counts, gamma, d)
-    if updates.ndim == 2:
-        weighted = jnp.einsum("k,kn->n", coeffs.astype(jnp.float32),
-                              updates.astype(jnp.float32))
-    else:
-        weighted = jnp.tensordot(coeffs.astype(jnp.float32),
-                                 updates.astype(jnp.float32), axes=(0, 0))
+    weighted = jnp.tensordot(coeffs.astype(jnp.float32),
+                             updates.astype(jnp.float32), axes=(0, 0))
     return m * weighted
-
-
-def opwa_aggregate_traced_k(updates: jax.Array, ks: jax.Array,
-                            coeffs: jax.Array, gamma: float, d: int = 1,
-                            active: Optional[jax.Array] = None,
-                            use_kernel="auto") -> jax.Array:
-    """OPWA aggregation fused with traced-k Top-K selection (the paper's
-    BCRS+OPWA hot path): updates [K, n] RAW flat client updates, ks [K] i32
-    traced retained counts — selection, overlap counts, the gamma mask, and
-    the weighted merge happen in one pipeline instead of materializing
-    values/masks first.
-
-    Kernel route: the two-kernel Pallas pipeline (``threshold_find`` +
-    ``fused_merge``) — 9 logical HBM passes over [K, n] vs ~35 unfused.
-    Reference route: ``topk_compress_batch`` + ``opwa_aggregate``,
-    bit-identical. ``active`` gates padded cohort rows out of the merge and
-    the overlap counts (engine semantics).
-    """
-    if resolve_use_kernel(use_kernel):
-        from repro.kernels import ops as kops
-        agg, _ = kops.megakernel_aggregate(
-            updates, ks, coeffs, active=active, opwa=True,
-            gamma=float(gamma), d=int(d))
-        return agg
-    from repro.core.compression import topk_compress_batch
-    c = topk_compress_batch(updates, ks)
-    vals, mask = c.values, c.mask
-    if active is not None:
-        vals = vals * active[:, None]
-        mask = mask & active[:, None]
-    return opwa_aggregate(vals, mask, coeffs, gamma, d, use_kernel=False)
 
 
 def bcrs_aggregate(updates: jax.Array, coeffs: jax.Array) -> jax.Array:

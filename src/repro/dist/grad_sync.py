@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import strategies as strat_mod
-from repro.core.compression import k_for_ratio_traced, resolve_use_kernel
+from repro.core.compression import k_for_ratio_traced
 from repro.fed.engine import compress_merge_leaf
 
 Metrics = Dict[str, jax.Array]
@@ -139,13 +139,6 @@ def make_compressed_train_step(model, opt, *, n_pods: int,
         raise ValueError(
             f"strategy {strategy!r} does not compress; use make_train_step "
             f"for dense sync")
-    opwa = strat.overlap_weighted
-    value_codec = strat.value_codec
-    kernel_codec = strat.kernel_codec
-    # codec strategies take the kernel route iff they registered a kernel
-    # lowering for their codec (fused_merge's quantize/dequantize stage)
-    use_kernel = (resolve_use_kernel(use_kernel)
-                  and (value_codec is None or kernel_codec is not None))
     grad_fn = _grad_fn(model)
 
     def step(params, opt_state, batch, pod_crs, pod_coeffs):
@@ -182,9 +175,8 @@ def make_compressed_train_step(model, opt, *, n_pods: int,
                         .reshape(g.shape[1:]), e)
             ks = k_for_ratio_traced(n, crs)
             agg, new_e = compress_merge_leaf(
-                gf, coeffs, ks, gamma=gamma, overlap_d=overlap_d, opwa=opwa,
-                use_kernel=use_kernel, residuals=e.reshape(n_pods, n),
-                value_codec=value_codec, kernel_codec=kernel_codec)
+                gf, coeffs, ks, strat, gamma=gamma, overlap_d=overlap_d,
+                use_kernel=use_kernel, residuals=e.reshape(n_pods, n))
             return agg.reshape(g.shape[1:]), new_e.reshape(e.shape)
 
         pairs = jax.tree.map(sync_leaf, grads, ef)

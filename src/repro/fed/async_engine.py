@@ -6,7 +6,7 @@ process (``ft.arrivals``: mid-transfer failures, resume-from-offset retries,
 exponential backoff, per-upload deadlines) into a K-slot buffer. When the
 buffer fills — or stalls past a configurable deadline and flushes partially —
 the server merges it in ONE compiled program: the same
-``engine.aggregate_updates`` substrate every synchronous engine uses, fed
+``engine.compress_merge_leaf`` substrate every synchronous engine uses, fed
 staleness-discounted coefficients (``w_i / (1 + s_i)^alpha``,
 ``core.bcrs.staleness_discount``) so updates computed against old versions
 count less.
@@ -198,9 +198,11 @@ def make_async_merge_step(acfg, *, eta: float = 1.0,
             res_rows = engine_mod.densify_rows(*residuals, flat.shape[0])
         else:
             res_rows = residuals if ef else None
-        agg, new_rows = engine_mod.aggregate_updates(
-            spec, x["updates"], x["weights"], x["ks"],
-            residuals=res_rows, active=x["active"])
+        agg, new_rows = engine_mod.compress_merge_leaf(
+            x["updates"], x["weights"], x["ks"], spec.strat,
+            gamma=spec.gamma, overlap_d=spec.overlap_d,
+            use_kernel=spec.use_kernel, residuals=res_rows,
+            active=x["active"])
         out = {"flat": flat - eta * agg,
                "overflow": jnp.asarray(False)}
         if layout == "topk_complement":
@@ -832,7 +834,7 @@ def run_async_sim(sim, acfg, rng, clients, parts, fracs_all, links, server,
     fracs_norm = fracs_norm / fracs_norm.sum()
     crs_all, coeffs_all, _info = agg_mod.round_schedule(
         acfg, n, fracs_norm, links, v_bytes)
-    ks_all = agg_mod.ks_for_schedule(n_params, crs_all, acfg)
+    ks_all = agg_mod.ks_for_schedule(n_params, crs_all)
     # dense wire formats return a scalar 1.0 — broadcast to per-client
     cr_eff_all = np.broadcast_to(np.asarray(
         strat.wire.cr_eff(np.asarray(crs_all, np.float64), n_params),

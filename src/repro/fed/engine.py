@@ -1,40 +1,35 @@
 """One compression/aggregation substrate for every FL round engine.
 
-Before this module the repo carried three divergent implementations of
-"compress each client's update, then merge": the fused round program
-(``fed.round_step``), the mesh-parallel round's inline float-space bisection
-(``fed.mesh_round``), and the compressed pod sync (``dist.grad_sync``). They
-are now thin adapters over the pure functions here:
+Every engine (the fused round program ``fed.round_step``, the scanned
+simulation, the async buffer merge, the mesh-parallel round
+``fed.mesh_round`` and the compressed pod sync ``dist.grad_sync``) is a thin
+adapter over the pure functions here:
 
   * ``ClientUpdateSpec``      — static description of the client-update
-                                pipeline (strategy, block/kernel routing,
-                                OPWA constants), derived from an
+                                pipeline (strategy, OPWA constants, kernel
+                                choice), derived from an
                                 ``AggregationConfig`` via ``spec_for``;
-  * ``aggregate_updates``     — flat-space path: [C, n] stacked updates ->
-                                traced-k compression (integer-bit bisection),
-                                batched error feedback, OPWA/weighted merge.
-                                Used by the fused per-round program and the
-                                scanned simulation;
-  * ``compress_merge_leaf``   — per-leaf path: [C, *shape] updates in their
-                                natural (possibly TP-sharded) layout. The
-                                bisection reduces over the non-client axes,
-                                so sharded leaves stay sharded. Used by
-                                ``mesh_round`` and ``grad_sync``;
-  * ``make_sim_scan``         — the fourth entry point: the ENTIRE
-                                multi-round simulation lowered into one
-                                ``lax.scan`` over rounds (server flat params
-                                + EF residuals threaded as carry, host-
-                                precomputed per-round schedules as xs).
-                                ONE compile per simulation, zero per-round
-                                dispatch.
+  * ``compress_merge_leaf``   — the one compress-and-merge function:
+                                [C, *shape] updates (a flat [C, n] matrix or
+                                one leaf in its natural, possibly TP-sharded
+                                layout) -> traced-k Top-K, error feedback,
+                                the strategy's codec, OPWA or weighted
+                                merge; the dense merge for strategies that
+                                do not compress;
+  * ``make_sim_scan``         — the ENTIRE multi-round simulation lowered
+                                into one ``lax.scan`` over rounds (server
+                                flat params + EF residuals threaded as
+                                carry, host-precomputed per-round schedules
+                                as xs). ONE compile per simulation, zero
+                                per-round dispatch.
 
-Every Top-K selection in the tree routes through
+Every Top-K selection in the tree has
 ``core.compression.topk_compress_dynamic`` semantics — the traced-k
-bit-pattern bisection (ties kept). With ``use_kernel`` on, the flat-space
-path lowers the WHOLE compress->EF->merge pipeline to two Pallas kernels
+bit-pattern bisection (ties kept). With ``use_kernel`` on, the whole
+compress->EF->merge pipeline lowers to two Pallas kernels
 (``kernels.threshold_find`` + ``kernels.fused_merge``) that are bit-exact
 with the jnp lowering while making ~9 logical HBM passes over the [C, n]
-update matrix instead of ~35; the jnp path stays as the parity reference.
+update matrix instead of ~35; the jnp route stays as the parity reference.
 """
 from __future__ import annotations
 
@@ -62,18 +57,16 @@ TRACE_COUNTS: collections.Counter = collections.Counter()
 @dataclass(frozen=True)
 class ClientUpdateSpec:
     """Static (trace-time) description of the per-client update pipeline:
-    compress (traced-k Top-K / blockwise / EF) -> OPWA or weighted merge.
-    All runtime quantities (per-client retained counts ``ks``, weights,
-    residuals) stay traced arguments of the functions below. Everything
-    strategy-shaped is read from the capability record
-    (``core.strategies.get``) — this module never matches strategy names."""
+    the registered strategy, its OPWA constants and the ``use_kernel``
+    tri-state, handed unchanged to ``compress_merge_leaf`` (which resolves
+    it). All runtime quantities (per-client retained counts ``ks``,
+    weights, residuals) stay traced arguments. Everything strategy-shaped
+    is read from the capability record (``core.strategies.get``) — this
+    module never matches strategy names."""
     strategy: str = "fedavg"
-    cr: float = 0.1                # static CR* (only the EF Pallas kernel
-    block_topk: bool = False       # needs it — everything else is traced)
-    block_size: int = 8192
     gamma: float = 5.0
     overlap_d: int = 1
-    use_kernel: bool = False       # resolved bool (never "auto")
+    use_kernel: object = False     # True | False | "auto"
 
     def __post_init__(self):
         strat_mod.get(self.strategy)   # config-time error, names listed
@@ -87,49 +80,12 @@ class ClientUpdateSpec:
     def needs_residuals(self) -> bool:
         return self.strat.needs_residuals
 
-    @property
-    def use_megakernel(self) -> bool:
-        # the traced-k Pallas pipeline (threshold_find + fused_merge) serves
-        # every global-top-k strategy at per-client traced ks — the paper's
-        # BCRS-faithful default. Block-top-k configs keep the traced-k jnp
-        # block path (per-block thresholds), dense strategies are already a
-        # single einsum pass, and codec strategies route through the
-        # kernel's quantize/dequantize stage iff they registered a
-        # kernel_codec (the megakernel capability is per-codec). NOTE the
-        # old `use_ef_kernel` route (static-CR ef_update kernel) is gone:
-        # it silently compressed at spec.cr even when the schedule passed
-        # varying traced ks.
-        return (self.use_kernel and not self.block_topk
-                and self.strat.megakernel and self.strat.compresses)
-
 
 def spec_for(acfg) -> ClientUpdateSpec:
-    """AggregationConfig -> ClientUpdateSpec (resolves use_kernel="auto")."""
-    return ClientUpdateSpec(
-        strategy=acfg.strategy, cr=acfg.cr, block_topk=acfg.block_topk,
-        block_size=acfg.block_size, gamma=acfg.gamma,
-        overlap_d=acfg.overlap_d,
-        use_kernel=comp.resolve_use_kernel(acfg.use_kernel))
-
-
-def compress_batch_fn(spec: ClientUpdateSpec) -> Callable:
-    """Batched traced-k compressor for the spec: [C, n], ks [C] -> Compressed.
-    When the strategy declares a ``value_codec``, the survivors come back
-    already dequantized — downstream EF/merge code needs no codec branch."""
-    if spec.block_topk:
-        base = lambda u, ks: comp.block_topk_compress_batch(
-            u, ks, block=spec.block_size)
-    else:
-        base = comp.topk_compress_batch
-    codec = spec.strat.value_codec
-    if codec is None:
-        return base
-
-    def compress(u, ks):
-        c = base(u, ks)
-        return comp.Compressed(codec(c.values, c.mask), c.mask)
-
-    return compress
+    """AggregationConfig -> ClientUpdateSpec."""
+    return ClientUpdateSpec(strategy=acfg.strategy, gamma=acfg.gamma,
+                            overlap_d=acfg.overlap_d,
+                            use_kernel=acfg.use_kernel)
 
 
 # ------------------------------------------------------------- flat <-> tree
@@ -244,157 +200,69 @@ def make_masked_local_trainer(loss_fn: Callable, lr: float):
     return local_train
 
 
-# -------------------------------------------------------- megakernel routing
-def _aggregate_megakernel(spec: ClientUpdateSpec, updates: jax.Array,
-                          w: jax.Array, ks: jax.Array,
-                          residuals: Optional[jax.Array],
-                          active: Optional[jax.Array]
-                          ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Traced-k Pallas pipeline: exact per-client thresholds in 8 streamed
-    HBM sweeps (``threshold_find``), then EF correction, masking, overlap
-    counts, the OPWA mask, and the weighted merge in ONE further pass
-    (``fused_merge``) — bit-exact with the jnp path below, ~9 logical HBM
-    passes over [C, n] instead of ~35 (see repro.roofline.kernel_bytes).
-
-    This route REPLACES the old ``ef_kernel_step`` (static-CR ``ef_update``
-    kernel), which silently compressed at ``spec.cr`` even when the BCRS
-    schedule passed varying traced ``ks`` — the megakernel honors the traced
-    per-client counts exactly (regression-tested in
-    tests/test_megakernel.py).
-
-    Codec strategies ride the same pipeline: the registered
-    ``kernel_codec`` selects fused_merge's quantize/dequantize stage, with
-    the per-client scale emitted by threshold_find on its already-streamed
-    sweep — bit-exact with the jnp ``value_codec`` path (DESIGN.md §10)."""
-    codec = spec.strat.kernel_codec or "none"
-    if spec.strat.overlap_weighted and not spec.needs_residuals:
-        agg = opwa_mod.opwa_aggregate_traced_k(
-            updates, ks, w, spec.gamma, spec.overlap_d, active=active,
-            use_kernel=True)
-        return agg, residuals
-    from repro.kernels import ops as kops
-    agg, new_res = kops.megakernel_aggregate(
-        updates, ks, w, residuals=residuals, active=active,
-        opwa=spec.strat.overlap_weighted, gamma=spec.gamma,
-        d=spec.overlap_d, codec=codec)
-    return agg, (new_res if spec.needs_residuals else residuals)
-
-
-# ------------------------------------------------------------ flat-space path
-def aggregate_updates(spec: ClientUpdateSpec, updates: jax.Array,
-                      weights: jax.Array, ks: jax.Array,
-                      residuals: Optional[jax.Array] = None,
-                      active: Optional[jax.Array] = None
-                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Compress + merge stacked flat client updates (pure, jit/vmap-safe).
-
-    updates [C, n] f32; weights [C] (data fracs or BCRS Eq. 6 coefficients);
-    ks [C] i32 traced retained counts (per block when ``spec.block_topk``);
-    residuals [C, n] EF state (required iff ``spec.needs_residuals``);
-    active: optional bool [C] — inactive rows (padded cohort slots in the
-    scanned simulation) contribute nothing to the merge or the OPWA overlap
-    counts, and their residuals pass through unchanged. Active rows are
-    multiplied by 1.0 / masked with True, so the no-mask arithmetic is
-    preserved bit-for-bit.
-
-    Returns (agg [n] f32, new_residuals | None).
-    """
-    w = weights.astype(jnp.float32)
-    strat = spec.strat
-    if strat.needs_residuals and residuals is None:
-        raise ValueError(f"{spec.strategy} needs residuals")
-    if spec.use_megakernel:
-        # traced-k Pallas pipeline: selection thresholds + the whole
-        # apply/merge in ~9 HBM passes; EF, OPWA, and active gating happen
-        # inside the kernels. Bit-exact with the jnp path below.
-        return _aggregate_megakernel(spec, updates, w, ks, residuals, active)
-
-    compress = compress_batch_fn(spec)
-    mask = None
-    new_res = residuals
-
-    if not strat.compresses:
-        vals = updates
-    elif strat.needs_residuals:
-        c_obj, new_res = comp.ef_compress_batch(
-            residuals, updates, ks, compress_batch=compress)
-        vals, mask = c_obj.values, c_obj.mask
-        if active is not None:
-            new_res = jnp.where(active[:, None], new_res, residuals)
-    else:
-        c_obj = compress(updates, ks)
-        vals, mask = c_obj.values, c_obj.mask
-
-    if active is not None:
-        # padded rows are all-zero updates, but a Top-K mask over zeros is
-        # all-True (ties at the threshold) — force them out of the overlap
-        # counts and the merge
-        vals = vals * active[:, None]
-        if mask is not None:
-            mask = mask & active[:, None]
-
-    if strat.overlap_weighted:
-        agg = opwa_mod.opwa_aggregate(vals, mask, w, spec.gamma,
-                                      spec.overlap_d,
-                                      use_kernel=spec.use_kernel)
-    else:
-        agg = jnp.einsum("k,kn->n", w, vals.astype(jnp.float32))
-    return agg, new_res
-
-
-# ------------------------------------------------------------- per-leaf path
-def compress_merge_leaf(updates: jax.Array, coeffs: jax.Array, ks: jax.Array,
-                        *, gamma: float = 1.0, overlap_d: int = 1,
-                        opwa: bool = True, use_kernel="auto",
+# ------------------------------------------------------ compress and merge
+def compress_merge_leaf(updates: jax.Array, coeffs: jax.Array,
+                        ks: Optional[jax.Array],
+                        strategy: strat_mod.Strategy, *, gamma: float,
+                        overlap_d: int, use_kernel="auto",
                         residuals: Optional[jax.Array] = None,
-                        active: Optional[jax.Array] = None,
-                        value_codec: Optional[Callable] = None,
-                        kernel_codec: Optional[str] = None
+                        active: Optional[jax.Array] = None
                         ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Compress + merge ONE leaf in its natural layout.
+    """Compress + merge the cohort's updates of ONE leaf (pure,
+    jit/vmap-safe) — the one compress-and-merge function of the tree.
 
-    updates: [C, *shape] per-client (or per-pod) leaf updates — the bisection
-    Top-K reduces over all non-client axes, so a TP-sharded leaf never gets
-    reshaped/gathered (see mesh_round). coeffs [C]; ks [C] i32 traced.
-    ``residuals`` (matching [C, *shape], f32) switches on error feedback.
-    ``opwa=False`` skips the overlap mask (plain weighted merge of the
-    compressed values). ``active`` (bool [C]) gates padded cohort slots out
-    of the merge, the overlap counts, and the residual update — the same
-    semantics as ``aggregate_updates``. ``use_kernel`` is the usual
-    tri-state (True / False / "auto" = TPU only, resolved here via
-    ``resolve_use_kernel`` so callers can pass "auto" straight through).
-    ``value_codec`` (a registry ``Strategy.value_codec``) is applied to the
-    survivors before the merge AND before the residual update, so EF absorbs
-    the codec error. ``kernel_codec`` (the registry's
-    ``Strategy.kernel_codec``) is the codec's kernel-route capability: when
-    set, the megakernel runs fused_merge's matching quantize/dequantize
-    stage — bit-exact with the jnp ``value_codec`` path — instead of
-    forcing the leaf back onto the jnp lowering.
+    updates: [C, *shape] per-client (or per-pod) updates — a flat [C, n]
+    matrix or a leaf in its natural layout: the bisection Top-K reduces over
+    all non-client axes, so a TP-sharded leaf never gets reshaped or
+    gathered on the jnp route (see mesh_round). coeffs [C] (data fracs or
+    BCRS Eq. 6 coefficients); ks [C] i32 traced retained counts (unused,
+    and may be None, when the strategy does not compress).
 
-    The kernel route runs the whole leaf through the traced-k megakernel
-    pipeline (``threshold_find`` + ``fused_merge``) on a [C, leaf_n] view —
-    bit-exact with the jnp path (per-client selection is over the whole leaf
-    either way, so the reshape changes nothing numerically). NOTE the view
-    merges the leaf's non-client axes, so on a TP-sharded leaf XLA inserts a
-    gather first; the jnp path stays fully sharding-preserving and remains
-    the default off-TPU.
+    ``strategy`` is the registered capability record: ``compresses``
+    selects the dense merge or the Top-K pipeline, ``overlap_weighted`` the
+    OPWA mask, ``value_codec`` / ``kernel_codec`` the codec applied to the
+    survivors before the merge AND before the residual update (so EF
+    absorbs the codec error). ``residuals`` (matching [C, *shape], f32)
+    switch on error feedback; a strategy that carries EF must be given
+    them. ``active`` (bool [C]) gates padded cohort slots out of the
+    merge, the overlap counts and the residual update: padded rows are
+    all-zero updates whose tie-at-zero Top-K mask is all-True.
 
-    Returns (agg [*shape] f32, new_residuals | None).
+    ``use_kernel`` is the tri-state (True / False / "auto" = TPU only),
+    resolved here and nowhere else. The kernel route runs the leaf through
+    the traced-k megakernel pipeline (``threshold_find`` + ``fused_merge``,
+    with fused_merge's quantize/dequantize stage for a ``kernel_codec``) on
+    a [C, leaf_n] view — bit-exact with the jnp route (per-client selection
+    is over the whole leaf either way). It serves every strategy whose
+    record declares ``megakernel``, which the registry allows only where
+    the kernel can take the strategy's codec. NOTE the view merges the
+    leaf's non-client axes, so on a TP-sharded leaf XLA inserts a gather
+    first; the jnp route stays sharding-preserving and is the default off
+    the TPU.
+
+    Returns (agg [*shape] f32, new_residuals | residuals).
     """
     w = coeffs.astype(jnp.float32)
+    if not strategy.compresses:
+        # dense exchange: one weighted sum, nothing to select or fuse
+        x = updates.astype(jnp.float32)
+        if active is not None:
+            x = x * active.reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.tensordot(w, x, axes=(0, 0)), residuals
+    if strategy.needs_residuals and residuals is None:
+        raise ValueError(f"{strategy.name} needs residuals")
     if active is not None:
         w = jnp.where(active, w, 0.0)
-    if ((value_codec is None or kernel_codec is not None)
-            and comp.resolve_use_kernel(use_kernel)):
+    if strategy.megakernel and comp.resolve_use_kernel(use_kernel):
         from repro.kernels import ops as kops
         c, shape = updates.shape[0], updates.shape[1:]
         u2 = updates.astype(jnp.float32).reshape(c, -1)
         r2 = (residuals.astype(jnp.float32).reshape(c, -1)
               if residuals is not None else None)
         agg2, new_res2 = kops.megakernel_aggregate(
-            u2, ks, w, residuals=r2, active=active, opwa=opwa,
-            gamma=float(gamma), d=int(overlap_d),
-            codec=kernel_codec or "none")
+            u2, ks, w, residuals=r2, active=active,
+            opwa=strategy.overlap_weighted, gamma=float(gamma),
+            d=int(overlap_d), codec=strategy.kernel_codec or "none")
         return (agg2.reshape(shape),
                 new_res2.reshape((c,) + shape) if residuals is not None
                 else None)
@@ -403,22 +271,20 @@ def compress_merge_leaf(updates: jax.Array, coeffs: jax.Array, ks: jax.Array,
         x = residuals + x
     c_obj = jax.vmap(comp.topk_compress_dynamic)(x, ks)
     vals, mask = c_obj.values, c_obj.mask
-    if value_codec is not None:
-        vals = value_codec(vals, mask)
+    if strategy.value_codec is not None:
+        vals = strategy.value_codec(vals, mask)
     new_res = (x - vals) if residuals is not None else None
     if active is not None:
-        # padded rows are all-zero updates whose tie-at-zero Top-K mask is
-        # all-True — gate them out of the merge/counts; their residuals
-        # pass through unchanged
+        # gate padded rows out of the merge/counts; their residuals pass
+        # through unchanged
         ax = active.reshape((-1,) + (1,) * (updates.ndim - 1))
         vals = vals * ax
         mask = mask & ax
         if new_res is not None:
             new_res = jnp.where(ax, new_res,
                                 residuals.astype(jnp.float32))
-    if opwa:
-        agg = opwa_mod.opwa_aggregate(vals, mask, w, gamma,
-                                      overlap_d, use_kernel=False)
+    if strategy.overlap_weighted:
+        agg = opwa_mod.opwa_aggregate(vals, mask, w, gamma, overlap_d)
     else:
         agg = jnp.tensordot(w, vals, axes=(0, 0))
     return agg, new_res
@@ -536,8 +402,9 @@ def make_sim_scan(loss_fn: Callable, params_template, *, lr: float,
             res_in = res
             if ef and "reset_ef" in p:
                 res_in = jnp.where(p["reset_ef"], jnp.zeros_like(res), res)
-        agg, new_res = aggregate_updates(
-            spec, updates, p["weights"], p["ks"],
+        agg, new_res = compress_merge_leaf(
+            updates, p["weights"], p["ks"], spec.strat, gamma=spec.gamma,
+            overlap_d=spec.overlap_d, use_kernel=spec.use_kernel,
             residuals=res_in if ef else None, active=active)
         if ef and per_client:
             # scatter updated rows back to the per-client store; padded

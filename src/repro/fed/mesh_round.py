@@ -85,9 +85,6 @@ def make_round_body(loss_fn: Callable, *, lr_local: float = 1e-2,
     strat = strat_mod.get(strategy)   # config-time error, names listed
     ef = strat.needs_residuals
     compress = strat.compresses
-    opwa = strat.overlap_weighted
-    value_codec = strat.value_codec
-    kernel_codec = strat.kernel_codec
     local_train = make_masked_local_trainer(loss_fn, lr_local)
 
     def body(params, residuals, batches, step_mask, coeffs, crs, active):
@@ -106,20 +103,11 @@ def make_round_body(loss_fn: Callable, *, lr_local: float = 1e-2,
             reshape(c, -1) would merge sharded dims and force XLA to gather
             the whole leaf per device (§Perf iteration 1)."""
             with jax.named_scope("fl.merge"):
-                if not compress:
-                    dl32 = dl.astype(jnp.float32)
-                    if active is not None:
-                        dl32 = dl32 * active.reshape(
-                            (-1,) + (1,) * (dl32.ndim - 1))
-                    agg, new_res = jnp.tensordot(w, dl32, axes=(0, 0)), res
-                else:
-                    n = dl.size // dl.shape[0]
-                    ks = comp.k_for_ratio_traced(n, crs)
-                    agg, new_res = compress_merge_leaf(
-                        dl, w, ks, gamma=gamma, overlap_d=overlap_d,
-                        opwa=opwa, use_kernel=use_kernel, residuals=res,
-                        active=active, value_codec=value_codec,
-                        kernel_codec=kernel_codec)
+                ks = (comp.k_for_ratio_traced(dl.size // dl.shape[0], crs)
+                      if compress else None)
+                agg, new_res = compress_merge_leaf(
+                    dl, w, ks, strat, gamma=gamma, overlap_d=overlap_d,
+                    use_kernel=use_kernel, residuals=res, active=active)
             with jax.named_scope("fl.server_step"):
                 new_p = (p.astype(jnp.float32) - eta * agg).astype(p.dtype)
             return new_p, new_res

@@ -475,7 +475,7 @@ def run_population_rounds(pop: Population, cfg: PopulationRunConfig, *,
         links_sel = [pop.links[c] for c in ids]          # O(C)
         crs, weights, info = agg_mod.round_schedule(acfg, len(ids), fr,
                                                     links_sel, v_bytes)
-        ks = agg_mod.ks_for_schedule(n_params, crs, acfg)
+        ks = agg_mod.ks_for_schedule(n_params, crs)
         if strat.wire.dense:
             rt = cost_model.uncompressed_round(links_sel, v_bytes)
         else:

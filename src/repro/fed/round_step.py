@@ -10,10 +10,10 @@ loop stays in ``fed.server.FLServer.round``.
   * clients are stacked on a leading axis and the local trainer is vmapped;
     ragged per-client step counts are handled with a step mask (padded steps
     are exact no-ops, so parity with the sequential loop is preserved);
-  * per-client compression uses the traced-k bisection Top-K
-    (``topk_compress_batch``) — one trace serves every BCRS schedule;
-  * on TPU the EF step runs through the fused ``ef_update`` Pallas kernel
-    and OPWA through ``overlap_combine`` (CPU/GPU interpret or XLA paths);
+  * compression, EF and the merge run through
+    ``engine.compress_merge_leaf`` on the [C, n] update matrix: the
+    traced-k bisection Top-K (one trace serves every BCRS schedule), and on
+    a TPU the ``threshold_find`` + ``fused_merge`` Pallas pipeline;
   * the server update ``w ← w − η·agg`` happens inside the same jit with the
     flat parameter and residual buffers donated; the stacked client batch
     buffers are re-staged every round by the harness's double-buffered
@@ -72,8 +72,7 @@ def make_round_step(loss_fn: Callable, params_template, *, lr: float,
              batches,                 # pytree of [C, S, ...] stacked batches
              step_mask [C, S] bool,   # padded-step validity
              weights [C] f32,         # data fracs or BCRS Eq. 6 coefficients
-             ks [C] i32,              # retained count per client (per block
-                                      # when acfg.block_topk)
+             ks [C] i32,              # retained count per client
              ks_overlap [C] i32)      # global top-k count for the Fig. 4
                                       # overlap counts (overlap variant only)
         -> {"flat", "residuals", "loss"[, "overlap_counts"]}
@@ -91,8 +90,10 @@ def make_round_step(loss_fn: Callable, params_template, *, lr: float,
         deltas, losses = jax.vmap(local_train, in_axes=(None, 0, 0))(
             params, batches, step_mask)
         updates = engine.flatten_client_trees(deltas)   # [C, n] f32
-        agg, new_res = engine.aggregate_updates(
-            spec, updates, weights, ks, residuals=residuals)
+        agg, new_res = engine.compress_merge_leaf(
+            updates, weights, ks, spec.strat, gamma=spec.gamma,
+            overlap_d=spec.overlap_d, use_kernel=spec.use_kernel,
+            residuals=residuals)
 
         out = {"flat": flat - eta * agg,
                "residuals": new_res,
@@ -176,7 +177,7 @@ def make_population_round_step(loss_fn: Callable, params_template, *,
     ``overflow`` (bool scalar) is True iff a row's residual outgrew the
     width; callers assert on it rather than silently truncating EF state.
     Inactive slots round-trip their residuals unchanged (same ``active``
-    semantics as ``aggregate_updates``), so the host can scatter only the
+    semantics as ``compress_merge_leaf``), so the host can scatter only the
     real cohort prefix back to the store.
     """
     spec = engine.spec_for(acfg)
@@ -207,8 +208,9 @@ def make_population_round_step(loss_fn: Callable, params_template, *,
             res_rows = engine.densify_rows(*residuals, n)
         else:
             res_rows = residuals if ef else None
-        agg, new_rows = engine.aggregate_updates(
-            spec, updates, x["weights"], x["ks"],
+        agg, new_rows = engine.compress_merge_leaf(
+            updates, x["weights"], x["ks"], strat, gamma=spec.gamma,
+            overlap_d=spec.overlap_d, use_kernel=spec.use_kernel,
             residuals=res_rows, active=active)
 
         n_act = jnp.maximum(jnp.sum(active.astype(jnp.int32)), 1)

@@ -126,8 +126,7 @@ class FLServer:
         links = self._selected_links(selected)
         crs, weights, info = agg_mod.round_schedule(
             self.acfg, k, data_fracs, links, self.v_bytes)
-        ks = jnp.asarray(agg_mod.ks_for_schedule(self.n_params, crs,
-                                                 self.acfg))
+        ks = jnp.asarray(agg_mod.ks_for_schedule(self.n_params, crs))
         if want_overlap:
             if self._fused_step_overlap is None:
                 raise RuntimeError(

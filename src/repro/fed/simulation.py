@@ -577,7 +577,7 @@ def _plan_rounds(sim, acfg, rng, clients, parts, fracs_all, links, server,
         links_sel = [links[i] for i in selected]
         crs, weights, info = agg_mod.round_schedule(acfg, c_r, fr, links_sel,
                                                     v_bytes)
-        ks = agg_mod.ks_for_schedule(n_params, crs, acfg)
+        ks = agg_mod.ks_for_schedule(n_params, crs)
         ks_overlap = (agg_mod.overlap_ks(acfg, info, c_r, n_params)
                       if collect_overlap and rnd == sim.rounds // 2
                       else None)
@@ -864,7 +864,7 @@ def run_fl_traced(sim: FLSimConfig, acfg: agg_mod.AggregationConfig,
     # schedule over the FULL client set is computable once on host)
     crs_all, coeffs_all, info = agg_mod.round_schedule(
         acfg, n, fracs_all / fracs_all.sum(), links, v_bytes)
-    ks_all = agg_mod.ks_for_schedule(n_params, crs_all, acfg)
+    ks_all = agg_mod.ks_for_schedule(n_params, crs_all)
     cr_eff = acfg.strat.wire.cr_eff(acfg.cr, n_params)
     times_all = np.array([bcrs_mod.comm_time(v_bytes, l, cr_eff)
                           for l in links], np.float32)

@@ -1,18 +1,18 @@
-"""Pallas TPU kernels for the compression hot path:
+"""Pallas TPU kernels:
 
   threshold_find   exact per-client k-th-magnitude thresholds at TRACED k
                    (16-ary bit-pattern bisection, 8 streamed sweeps)
   fused_merge      traced-k apply/merge megakernel: EF correction, Top-K
-                   masking, overlap counts, OPWA mask, and the weighted
-                   aggregate in ONE pass over each (updates, residuals) tile
-  block_topk       per-VMEM-block magnitude Top-K at static k
-  overlap_combine  fused OPWA aggregation (counts + mask + weighted sum)
-  ef_update        fused error-feedback Top-K step at static k
+                   masking, the codec stage, overlap counts, OPWA mask, and
+                   the weighted aggregate in ONE pass over each (updates,
+                   residuals) block
+  expert_gmm       grouped matmuls of the MoE layer's held experts
+  flash_attention  blockwise causal attention
 
 ``threshold_find`` + ``fused_merge`` form the traced-k megakernel pipeline
-behind ``fed.engine.aggregate_updates`` — the route that serves the paper's
-bandwidth-adaptive per-client CRs; the three static-k kernels are the
-special cases it subsumes. Each kernel has a pure-jnp oracle in ref.py and a
-jit'd wrapper in ops.py; validated in interpret mode on CPU, targeted at TPU
-VMEM tiling (8 x 128 lanes).
+behind the kernel route of ``fed.engine.compress_merge_leaf`` — the route
+that serves the paper's bandwidth-adaptive per-client CRs. Each kernel has a
+jit'd wrapper in ops.py, and all but ``expert_gmm`` a pure-jnp oracle in
+ref.py; validated in interpret mode on CPU, targeted at TPU VMEM tiling
+(8 x 128 lanes).
 """

@@ -18,9 +18,9 @@ chunk of lanes and entirely in VMEM:
     residual' = corrected - send             (inactive rows pass through)
 
 writing only the aggregate [1, n] (plus the residuals for EF configs)
-back to HBM. It generalizes and subsumes the three static-k kernels
-(``block_topk``'s selection, ``ef_update``'s EF arithmetic,
-``overlap_combine``'s merge) at traced per-client k.
+back to HBM: the Top-K selection, the EF arithmetic and the OPWA merge
+at traced per-client k, in one pass over each block, every intermediate
+kept in VMEM.
 
 The codec stage (``codec="int8"|"int4"``) quantizes the send values onto the
 symmetric integer grid with the per-client ``scales`` column (derived from
@@ -53,11 +53,11 @@ lanes past it are never written (nor copied back).
 
 Bit-exactness contract (asserted in tests/test_megakernel.py on the CPU and
 by chip_smoke.py on a TPU): every intermediate uses the same op sequence as
-the jnp reference in ``fed.engine.aggregate_updates`` — so agg and residuals
+the jnp route of ``fed.engine.compress_merge_leaf`` — so agg and residuals
 match the traced jnp path bit for bit, per lane, including the all-True tie
 masks of all-zero rows. The weighted sum is an f32 multiply and a sum over
-the client axis: XLA on a TPU lowers the reference's ``einsum("k,kn->n")``
-(and the per-leaf ``tensordot``, at any matmul precision) to exactly that,
+the client axis: XLA on a TPU lowers the reference's ``tensordot`` (at any
+matmul precision) to exactly that,
 while a Mosaic ``dot_general`` runs on the MXU and rounds differently at
 DEFAULT and HIGHEST precision alike (measured on a v5e at C=2 and C=8).
 

@@ -9,15 +9,11 @@ import jax
 import jax.numpy as jnp
 from jax import custom_batching
 
-from repro.core.compression import Compressed, k_for_ratio
 from repro.core.strategies import CODEC_LEVELS, quantization_scale
-from repro.kernels.block_topk import ROWS_TILE, block_topk_pallas
-from repro.kernels.ef_update import ef_update_pallas
 from repro.kernels.expert_gmm import expert_gmm_pallas, expert_tgmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_merge import fused_merge_pallas
 from repro.kernels.fused_merge import TILE_N as MERGE_TILE
-from repro.kernels.overlap_combine import TILE_N, overlap_combine_pallas
 from repro.kernels.threshold_find import threshold_find_pallas
 from repro.kernels.threshold_find import TILE_N as THRESH_TILE
 
@@ -30,42 +26,6 @@ def _interpret() -> bool:
         raise RuntimeError(
             f"the Pallas TPU kernels cannot run on platform {platform!r}")
     return platform == "cpu"
-
-
-def _pad_rows(n_rows: int) -> int:
-    return (-n_rows) % ROWS_TILE
-
-
-@functools.partial(jax.jit, static_argnames=("cr", "block"))
-def block_topk(u: jax.Array, cr: float, block: int = 8192) -> Compressed:
-    """Flat vector -> block-top-k Compressed (kernel-backed)."""
-    n = u.shape[0]
-    n_pad = (-n) % block
-    up = jnp.pad(u.astype(jnp.float32), (0, n_pad))
-    nb = up.shape[0] // block
-    x2d = up.reshape(nb, block)
-    rpad = _pad_rows(nb)
-    if rpad:
-        x2d = jnp.pad(x2d, ((0, rpad), (0, 0)))
-    k = k_for_ratio(block, cr)
-    vals, mask = block_topk_pallas(x2d, k, interpret=_interpret())
-    vals = vals[:nb].reshape(-1)[:n].astype(u.dtype)
-    mask = mask[:nb].reshape(-1)[:n] > 0
-    return Compressed(vals, mask)
-
-
-@functools.partial(jax.jit, static_argnames=("gamma", "d"))
-def overlap_combine(vals: jax.Array, masks: jax.Array, coeffs: jax.Array,
-                    gamma: float, d: int) -> jax.Array:
-    """[K,n] masked updates + [K,n] masks + [K] coeffs -> OPWA-aggregated [n]."""
-    k, n = vals.shape
-    n_pad = (-n) % TILE_N
-    v = jnp.pad(vals.astype(jnp.float32), ((0, 0), (0, n_pad)))
-    m = jnp.pad(masks.astype(jnp.int8), ((0, 0), (0, n_pad)))
-    out = overlap_combine_pallas(v, m, coeffs.astype(jnp.float32),
-                                 float(gamma), int(d),
-                                 interpret=_interpret())
-    return out[0, :n]
 
 
 # ------------------------------------------------- traced-k megakernel pipeline
@@ -109,7 +69,7 @@ def megakernel_aggregate(updates: jax.Array, ks: jax.Array,
     scales (and everything downstream) match bit for bit.
 
     Returns (agg [n] f32, new_residuals [C, n] | None) — bit-exact with the
-    jnp path of ``fed.engine.aggregate_updates``.
+    jnp route of ``fed.engine.compress_merge_leaf``.
     """
     c, n = updates.shape
     n_pad = (-n) % MERGE_TILE
@@ -136,25 +96,6 @@ def megakernel_aggregate(updates: jax.Array, ks: jax.Array,
         return out[0, :n], None
     agg, new_res = out
     return agg[0, :n], new_res[:, :n]
-
-
-@functools.partial(jax.jit, static_argnames=("cr", "block"))
-def ef_topk_update(g: jax.Array, residual: jax.Array, cr: float,
-                   block: int = 8192):
-    """Fused EF step on flat vectors -> (send [n], new_residual [n])."""
-    n = g.shape[0]
-    n_pad = (-n) % block
-    gp = jnp.pad(g.astype(jnp.float32), (0, n_pad))
-    ep = jnp.pad(residual.astype(jnp.float32), (0, n_pad))
-    nb = gp.shape[0] // block
-    g2d, e2d = gp.reshape(nb, block), ep.reshape(nb, block)
-    rpad = _pad_rows(nb)
-    if rpad:
-        g2d = jnp.pad(g2d, ((0, rpad), (0, 0)))
-        e2d = jnp.pad(e2d, ((0, rpad), (0, 0)))
-    k = k_for_ratio(block, cr)
-    send, new_e = ef_update_pallas(g2d, e2d, k, interpret=_interpret())
-    return (send[:nb].reshape(-1)[:n], new_e[:nb].reshape(-1)[:n])
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -5,24 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def block_topk_ref(x2d: jax.Array, k: int):
-    """[nb, block] -> (values, mask int8). Keeps |x| >= k-th largest (ties kept)."""
-    mag = jnp.abs(x2d.astype(jnp.float32))
-    thresh = jax.lax.top_k(mag, k)[0][:, -1:]
-    mask = mag >= thresh
-    return jnp.where(mask, x2d, 0), mask.astype(jnp.int8)
-
-
-def overlap_combine_ref(vals: jax.Array, masks: jax.Array, coeffs: jax.Array,
-                        gamma: float, d: int) -> jax.Array:
-    """[K,n] masked values, [K,n] masks, [K] coeffs -> [1,n] f32."""
-    counts = jnp.sum(masks.astype(jnp.int32), axis=0, keepdims=True)
-    weighted = jnp.einsum("k,kn->n", coeffs.astype(jnp.float32),
-                          vals.astype(jnp.float32))[None, :]
-    m = jnp.where((counts > 0) & (counts <= d), jnp.float32(gamma), 1.0)
-    return m * weighted
-
-
 def threshold_find_ref(x2d: jax.Array, ks: jax.Array,
                        e2d: jax.Array | None = None) -> jax.Array:
     """Traced-k thresholds [C, 1] u32: the k-th-largest |.| bit pattern per
@@ -45,7 +27,7 @@ def fused_merge_ref(x2d: jax.Array, thresholds: jax.Array, weights: jax.Array,
                     *, opwa: bool = False, gamma: float = 1.0, d: int = 1,
                     codec: str = "none", scales: jax.Array | None = None):
     """Oracle for the apply/merge megakernel: same op sequence as the jnp
-    path in ``fed.engine.aggregate_updates``. ``codec`` + ``scales`` [C, 1]
+    route of ``fed.engine.compress_merge_leaf``. ``codec`` + ``scales`` [C, 1]
     mirror the kernel's quantization stage (survivors dequantized before the
     merge; EF absorbs the quantization error). Returns agg [1, n] (plus
     new_residuals [C, n] when ``e2d`` is given)."""
@@ -72,12 +54,6 @@ def fused_merge_ref(x2d: jax.Array, thresholds: jax.Array, weights: jax.Array,
                       jnp.float32(1.0))
         weighted = m * weighted
     return weighted if e2d is None else (weighted, new_res)
-
-
-def ef_update_ref(g2d: jax.Array, e2d: jax.Array, k: int):
-    corrected = e2d.astype(jnp.float32) + g2d.astype(jnp.float32)
-    send, _ = block_topk_ref(corrected, k)
-    return send, corrected - send
 
 
 def flash_attention_ref(q, k, v, causal: bool = True):

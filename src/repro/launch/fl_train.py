@@ -474,7 +474,7 @@ def _run_async(cfg: FLTrainConfig, model, model_cfg, params, links, strat,
                          k_for_ratio(n_flat, float(crs_arr.flat[0])),
                          np.int32)
     else:
-        ks_all = agg_mod.ks_for_schedule(n_flat, crs_all, acfg)
+        ks_all = agg_mod.ks_for_schedule(n_flat, crs_all)
     cr_eff_all = np.broadcast_to(np.asarray(
         strat.wire.cr_eff(crs_arr, n_flat), np.float64), (n_reg,))
 

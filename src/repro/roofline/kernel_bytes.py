@@ -1,5 +1,5 @@
 """HBM-traffic accounting for the client-merge hot path: the traced-k Pallas
-megakernel pipeline vs the unfused XLA lowering of ``aggregate_updates``.
+megakernel pipeline vs the unfused XLA lowering of ``compress_merge_leaf``.
 
 Two complementary accountings, compared in ``BENCH_kernels.json``:
 
@@ -24,11 +24,8 @@ round) on one device.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import strategies as strat_mod
 from repro.roofline.hlo_cost import analyze_hlo
@@ -115,26 +112,21 @@ def wire_stream_bytes(strategy: str, n: int, k: int) -> dict:
             "total_ratio": float(total) / (ref_pair * k)}
 
 
-def unfused_merge_bytes(spec, c: int, n: int,
-                        platform: Optional[str] = None) -> dict:
-    """Trip-count-aware HBM bytes of the unfused (jnp) ``aggregate_updates``
+def unfused_merge_bytes(spec, c: int, n: int) -> dict:
+    """Trip-count-aware HBM bytes of the unfused (jnp) ``compress_merge_leaf``
     lowering for a [C, n] merge, plus XLA's uncorrected ``cost_analysis``
-    number. ``spec``: a ``fed.engine.ClientUpdateSpec`` with
-    ``use_kernel=False``.
+    number. ``spec``: a ``fed.engine.ClientUpdateSpec``; its ``use_kernel``
+    is ignored — the baseline is always the jnp route.
     """
-    from repro.fed.engine import aggregate_updates
-    assert not spec.use_kernel, "baseline must be the jnp lowering"
+    from repro.fed.engine import compress_merge_leaf
     u = jnp.zeros((c, n), jnp.float32)
     w = jnp.ones((c,), jnp.float32) / c
     ks = jnp.ones((c,), jnp.int32)
-    args = [u, w, ks]
-    if spec.needs_residuals:
-        fn = jax.jit(lambda u, w, ks, r: aggregate_updates(
-            spec, u, w, ks, residuals=r))
-        args.append(jnp.zeros((c, n), jnp.float32))
-    else:
-        fn = jax.jit(lambda u, w, ks: aggregate_updates(spec, u, w, ks))
-    compiled = fn.lower(*args).compile()
+    r = jnp.zeros((c, n), jnp.float32) if spec.needs_residuals else None
+    fn = jax.jit(lambda u, w, ks, r: compress_merge_leaf(
+        u, w, ks, spec.strat, gamma=spec.gamma, overlap_d=spec.overlap_d,
+        use_kernel=False, residuals=r))
+    compiled = fn.lower(u, w, ks, r).compile()
     cost = analyze_hlo(compiled.as_text(), 1)
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):

@@ -65,28 +65,6 @@ class TestDynamicTopK:
         np.testing.assert_array_equal(np.asarray(dyn.mask), np.asarray(ref_mask))
 
 
-class TestBlockTopK:
-    def test_ratio_preserved_per_block(self):
-        u = _vec(3, 8192 * 3)
-        c = C.block_topk_compress(u, 0.1, block=8192)
-        m = np.asarray(c.mask).reshape(3, 8192)
-        assert (m.sum(1) == 819).all()
-
-    def test_padding_tail(self):
-        u = _vec(4, 10000)
-        c = C.block_topk_compress(u, 0.1, block=8192)
-        assert c.values.shape == (10000,)
-        assert int(c.mask.sum()) >= C.k_for_ratio(10000, 0.1)
-
-    def test_close_to_global_mass(self):
-        """Block top-k retains nearly the mass of exact global top-k."""
-        u = _vec(5, 65536)
-        g = C.topk_compress(u, 0.1)
-        b = C.block_topk_compress(u, 0.1, block=4096)
-        mass = lambda c: float(jnp.sum(c.values.astype(jnp.float32) ** 2))
-        assert mass(b) >= 0.95 * mass(g)
-
-
 class TestErrorFeedback:
     def test_conservation(self):
         """send + residual' == residual + g (nothing is lost)."""
@@ -132,19 +110,6 @@ class TestSparseFormat:
         idx, vals = C.to_sparse(c, k)
         dense = C.from_sparse(idx, vals, n)
         np.testing.assert_allclose(np.asarray(dense), np.asarray(c.values),
-                                   rtol=1e-6)
-
-    @given(st.integers(128, 1500), st.integers(1, 4), SEED)
-    @settings(max_examples=15, deadline=None)
-    def test_roundtrip_block(self, n, kc, seed):
-        """Round-trip through the blockwise compressor (uneven tail block)."""
-        u = jax.random.normal(jax.random.PRNGKey(seed), (1, n))
-        ks = jnp.asarray([kc * 8], jnp.int32)
-        c = C.block_topk_compress_batch(u, ks, block=256)
-        kept = int(c.mask[0].sum())
-        idx, vals = C.to_sparse(C.Compressed(c.values[0], c.mask[0]), kept)
-        dense = C.from_sparse(idx, vals, n)
-        np.testing.assert_allclose(np.asarray(dense), np.asarray(c.values[0]),
                                    rtol=1e-6)
 
     def test_overallocated_k(self):

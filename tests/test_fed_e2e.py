@@ -197,7 +197,7 @@ class TestMeshRoundStepParity:
                           .astype(jnp.int32), 1, leaf_n)
             comp = jax.vmap(topk_compress_dynamic)(dl, ks)
             agg = opwa_aggregate(comp.values, comp.mask, coeffs, gamma,
-                                 d=1, use_kernel=False)
+                                 d=1)
             expect = params[name].astype(jnp.float32) - agg
             np.testing.assert_allclose(np.asarray(p_mesh[name]),
                                        np.asarray(expect),

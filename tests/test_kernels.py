@@ -1,90 +1,112 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp
+oracle, and the merge kernels through ``compress_merge_leaf``'s two routes
+(kernel vs jnp, bit for bit)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import strategies
+from repro.fed.engine import compress_merge_leaf
 from repro.kernels import ops, ref
-from repro.kernels.block_topk import block_topk_pallas
-from repro.kernels.ef_update import ef_update_pallas
-from repro.kernels.overlap_combine import overlap_combine_pallas
 
 SHAPES_2D = [(8, 128), (8, 1024), (16, 8192), (32, 512), (8, 2048)]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
-class TestBlockTopK:
+def _routes(strategy, u, w, ks, residuals=None, gamma=5.0, d=1):
+    """compress_merge_leaf through the jnp route and the kernel route."""
+    strat = strategies.get(strategy)
+    return [compress_merge_leaf(u, w, ks, strat, gamma=gamma, overlap_d=d,
+                                use_kernel=uk, residuals=residuals)
+            for uk in (False, True)]
+
+
+def _assert_routes_equal(outs):
+    (agg_j, res_j), (agg_k, res_k) = outs
+    np.testing.assert_array_equal(np.asarray(agg_k), np.asarray(agg_j))
+    if res_j is not None:
+        np.testing.assert_array_equal(np.asarray(res_k), np.asarray(res_j))
+
+
+class TestThresholdFindGrid:
+    """``ops.topk_thresholds`` against the bisection oracle over [C, n]
+    shapes, in f32 and in bf16 deltas cast to f32 (what every cell's
+    bf16 model feeds the merge)."""
+
     @pytest.mark.parametrize("shape", SHAPES_2D)
     @pytest.mark.parametrize("dtype", DTYPES)
     def test_vs_ref(self, shape, dtype):
         x = jax.random.normal(jax.random.PRNGKey(0), shape).astype(dtype)
-        k = max(1, shape[1] // 10)
-        kv, km = block_topk_pallas(x, k, interpret=True)
-        rv, rm = ref.block_topk_ref(x, k)
-        np.testing.assert_array_equal(np.asarray(km), np.asarray(rm))
-        np.testing.assert_allclose(np.asarray(kv, np.float32),
-                                   np.asarray(rv, np.float32), rtol=1e-6)
+        x = x.astype(jnp.float32)
+        c, n = shape
+        ks = jnp.full((c,), max(1, n // 10), jnp.int32).at[0].set(1)
+        np.testing.assert_array_equal(
+            np.asarray(ops.topk_thresholds(x, ks)),
+            np.asarray(ref.threshold_find_ref(x, ks))[:, 0])
 
     @pytest.mark.parametrize("k", [1, 7, 128, 1024])
     def test_k_sweep(self, k):
-        x = jax.random.normal(jax.random.PRNGKey(1), (8, 1024))
-        kv, km = block_topk_pallas(x, k, interpret=True)
-        assert (np.asarray(km).sum(axis=1) == k).all()
-
-    def test_flat_wrapper_matches_core(self):
-        u = jax.random.normal(jax.random.PRNGKey(2), (100_000,))
-        from repro.core.compression import block_topk_compress
-        a = block_topk_compress(u, 0.1, block=8192, use_kernel=False)
-        b = ops.block_topk(u, 0.1, block=8192)
-        np.testing.assert_allclose(np.asarray(a.values), np.asarray(b.values),
-                                   rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(a.mask), np.asarray(b.mask))
+        """Both routes keep exactly k per client: the survivors are the
+        EF residual's exact zeros."""
+        u = jax.random.normal(jax.random.PRNGKey(1), (8, 1024))
+        e = jax.random.normal(jax.random.PRNGKey(2), (8, 1024)) * 0.3
+        w = jnp.full((8,), 1 / 8)
+        outs = _routes("eftopk", u, w, jnp.full((8,), k, jnp.int32),
+                       residuals=e)
+        _assert_routes_equal(outs)
+        kept = np.sum(np.asarray(outs[1][1]) == 0.0, axis=1)
+        assert (kept == k).all()
 
 
-class TestOverlapCombine:
+class TestOPWAMergeRoutes:
+    """The OPWA merge (counts, gamma mask, weighted sum) of ``bcrs_opwa``
+    leaves: kernel route against jnp route, bit for bit."""
+
     @pytest.mark.parametrize("k_clients", [2, 5, 10, 16])
     @pytest.mark.parametrize("n", [1024, 4096, 10240])
-    def test_vs_ref(self, k_clients, n):
+    def test_vs_jnp(self, k_clients, n):
         key = jax.random.PRNGKey(k_clients * 1000 + n)
-        vals = jax.random.normal(key, (k_clients, n))
-        vals = vals * (jax.random.uniform(jax.random.PRNGKey(1), (k_clients, n)) < 0.1)
-        masks = (vals != 0)
-        coeffs = jax.random.uniform(jax.random.PRNGKey(2), (k_clients,))
-        out = overlap_combine_pallas(vals, masks, coeffs, 5.0, 1,
-                                     interpret=True)
-        r = ref.overlap_combine_ref(vals, masks, coeffs, 5.0, 1)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(r), rtol=1e-5,
-                                   atol=1e-6)
+        u = jax.random.normal(key, (k_clients, n))
+        w = jax.random.uniform(jax.random.PRNGKey(2), (k_clients,))
+        ks = jnp.full((k_clients,), n // 10, jnp.int32)
+        _assert_routes_equal(_routes("bcrs_opwa", u, w, ks))
 
     @pytest.mark.parametrize("gamma,d", [(1.0, 1), (3.0, 2), (10.0, 1)])
     def test_gamma_d_sweep(self, gamma, d):
-        vals = jax.random.normal(jax.random.PRNGKey(3), (6, 2048))
-        vals = vals * (jnp.abs(vals) > 1.0)
-        masks = vals != 0
-        coeffs = jnp.full((6,), 1 / 6)
-        out = ops.overlap_combine(vals, masks, coeffs, gamma, d)
-        r = ref.overlap_combine_ref(vals, masks, coeffs, gamma, d)[0]
-        np.testing.assert_allclose(np.asarray(out), np.asarray(r), rtol=1e-5,
-                                   atol=1e-6)
+        u = jax.random.normal(jax.random.PRNGKey(3), (6, 2048))
+        w = jnp.full((6,), 1 / 6)
+        ks = jnp.asarray([100, 200, 300, 400, 500, 600], jnp.int32)
+        _assert_routes_equal(_routes("bcrs_opwa", u, w, ks, gamma=gamma,
+                                     d=d))
 
 
-class TestEFUpdate:
+class TestEFMergeRoutes:
+    """``eftopk`` leaves of a 4-client cohort: the error-feedback step on
+    both routes (the kernel route on the leaf's [C, n] view), bit for bit,
+    aggregate and new residuals."""
+
     @pytest.mark.parametrize("shape", SHAPES_2D)
-    def test_vs_ref(self, shape):
-        g = jax.random.normal(jax.random.PRNGKey(4), shape)
-        e = jax.random.normal(jax.random.PRNGKey(5), shape)
-        k = max(1, shape[1] // 20)
-        ks, ke = ef_update_pallas(g, e, k, interpret=True)
-        rs, re = ref.ef_update_ref(g, e, k)
-        np.testing.assert_allclose(np.asarray(ks), np.asarray(rs), rtol=1e-6)
-        np.testing.assert_allclose(np.asarray(ke), np.asarray(re), rtol=1e-6)
+    def test_vs_jnp(self, shape):
+        c = 4
+        g = jax.random.normal(jax.random.PRNGKey(4), (c,) + shape)
+        e = jax.random.normal(jax.random.PRNGKey(5), (c,) + shape)
+        w = jnp.asarray([0.4, 0.3, 0.2, 0.1])
+        k = max(1, shape[0] * shape[1] // 20)
+        ks = jnp.asarray([k, k // 2 + 1, 2 * k, 1], jnp.int32)
+        _assert_routes_equal(_routes("eftopk", g, w, ks, residuals=e))
 
     def test_conservation(self):
-        g = jax.random.normal(jax.random.PRNGKey(6), (30_000,))
-        e = jax.random.normal(jax.random.PRNGKey(7), (30_000,))
-        s, ne = ops.ef_topk_update(g, e, 0.05, block=4096)
-        np.testing.assert_allclose(np.asarray(s + ne), np.asarray(g + e),
-                                   rtol=1e-5)
+        """One client at weight 1 on the kernel route: the merged send plus
+        the new residual is exactly residual + update — nothing is lost."""
+        g = jax.random.normal(jax.random.PRNGKey(6), (1, 30_000))
+        e = jax.random.normal(jax.random.PRNGKey(7), (1, 30_000))
+        ks = jnp.asarray([1500], jnp.int32)
+        agg, new_e = compress_merge_leaf(
+            g, jnp.ones((1,)), ks, strategies.get("eftopk"), gamma=1.0,
+            overlap_d=1, use_kernel=True, residuals=e)
+        np.testing.assert_array_equal(np.asarray(agg + new_e[0]),
+                                      np.asarray(e[0] + g[0]))
 
 
 class TestFlashAttention:

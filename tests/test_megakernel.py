@@ -3,7 +3,7 @@ reference path across every strategy, per-client ks pattern, and padding
 edge, plus the regression for the old static-CR EF-kernel route.
 
 Everything runs the kernels in interpret mode (this suite executes on CPU);
-the jnp path of ``fed.engine.aggregate_updates`` is the parity oracle.
+the jnp route of ``fed.engine.compress_merge_leaf`` is the parity oracle.
 """
 import jax
 import jax.numpy as jnp
@@ -13,7 +13,7 @@ import pytest
 from hyputil import given, settings, st
 
 from repro.core import compression as C
-from repro.core.opwa import opwa_aggregate_traced_k
+from repro.core import strategies
 from repro.fed import engine
 from repro.kernels import ops, ref
 from repro.kernels.fused_merge import block_lanes as merge_block_lanes
@@ -162,20 +162,20 @@ class TestFusedMerge:
                                           np.asarray(want))
 
 
-def _agg_both(strategy, u, w, ks, residuals=None, active=None, **spec_kw):
-    """aggregate_updates through the kernel route and the jnp reference."""
-    res = dict()
-    for use_kernel in (False, True):
-        spec = engine.ClientUpdateSpec(strategy=strategy,
-                                       use_kernel=use_kernel, **spec_kw)
-        res[use_kernel] = engine.aggregate_updates(
-            spec, u, w, ks, residuals=residuals, active=active)
-    return res
+def _agg_both(strategy, u, w, ks, residuals=None, active=None, gamma=5.0,
+              overlap_d=1):
+    """compress_merge_leaf through the kernel route and the jnp reference."""
+    strat = strategies.get(strategy)
+    return {use_kernel: engine.compress_merge_leaf(
+                u, w, ks, strat, gamma=gamma, overlap_d=overlap_d,
+                use_kernel=use_kernel, residuals=residuals, active=active)
+            for use_kernel in (False, True)}
 
 
 class TestAggregateUpdatesParity:
-    """Kernel-routed aggregate_updates must match the traced jnp path BIT
-    FOR BIT for all five strategies with per-client traced ks."""
+    """Kernel-routed compress_merge_leaf on the [C, n] matrix must match the
+    traced jnp route BIT FOR BIT for all five strategies with per-client
+    traced ks."""
 
     @pytest.mark.parametrize("strategy", STRATEGIES)
     def test_bit_exact(self, strategy):
@@ -220,9 +220,9 @@ class TestAggregateUpdatesParity:
 
 
 class TestEFKernelKsRegression:
-    """The old ``use_ef_kernel`` route compressed at the STATIC spec.cr,
-    silently ignoring varying traced ks. Both kernel-on EF configs must now
-    honor the per-client counts exactly."""
+    """The old ``use_ef_kernel`` route compressed at a STATIC CR, silently
+    ignoring varying traced ks. The kernel-on EF route must honor the
+    per-client counts exactly."""
 
     def _varying(self):
         u, e, w, _ = _case(6, 4096, seed=44)
@@ -233,17 +233,7 @@ class TestEFKernelKsRegression:
 
     def test_global_ef_kernel_honors_traced_ks(self):
         u, e, w, ks = self._varying()
-        out = _agg_both("eftopk", u, w, ks, residuals=e, cr=0.1)
-        np.testing.assert_array_equal(np.asarray(out[True][0]),
-                                      np.asarray(out[False][0]))
-        np.testing.assert_array_equal(np.asarray(out[True][1]),
-                                      np.asarray(out[False][1]))
-
-    def test_block_ef_kernel_config_honors_traced_ks(self):
-        u, e, w, _ = self._varying()
-        ks_block = jnp.asarray([1, 8, 64, 256, 410, 512], jnp.int32)
-        out = _agg_both("eftopk", u, w, ks_block, residuals=e,
-                        cr=0.1, block_topk=True, block_size=512)
+        out = _agg_both("eftopk", u, w, ks, residuals=e)
         np.testing.assert_array_equal(np.asarray(out[True][0]),
                                       np.asarray(out[False][0]))
         np.testing.assert_array_equal(np.asarray(out[True][1]),
@@ -251,48 +241,15 @@ class TestEFKernelKsRegression:
 
     def test_retained_counts_follow_ks_not_cr(self):
         """Direct symptom check: retained count per client == ks, not the
-        static-CR count the old kernel route produced."""
-        u, e, _, ks = self._varying()
-        spec = engine.ClientUpdateSpec(strategy="eftopk", use_kernel=True,
-                                       cr=0.1)
-        comp_obj, _ = C.ef_compress_batch(e, u, ks, use_kernel=True)
-        kept = np.asarray(jnp.sum(comp_obj.mask, axis=1))
+        static-CR count the old kernel route produced. The survivors are
+        the exact zeros of the kernel route's new residuals."""
+        u, e, w, ks = self._varying()
+        assert strategies.get("eftopk").megakernel
+        _, new_res = engine.compress_merge_leaf(
+            u, w, ks, strategies.get("eftopk"), gamma=1.0, overlap_d=1,
+            use_kernel=True, residuals=e)
+        kept = np.sum(np.asarray(new_res) == 0.0, axis=1)
         np.testing.assert_array_equal(kept, np.asarray(ks))
-        assert spec.use_megakernel
-
-
-class TestCompressionKernelRoutes:
-    def test_topk_compress_batch_kernel_route(self):
-        u, _, _, ks = _case(5, 3333, seed=9)
-        a = C.topk_compress_batch(u, ks)
-        b = C.topk_compress_batch(u, ks, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(a.mask), np.asarray(b.mask))
-        np.testing.assert_array_equal(np.asarray(a.values),
-                                      np.asarray(b.values))
-
-    def test_ef_compress_batch_kernel_route(self):
-        u, e, _, ks = _case(5, 3333, seed=10)
-        a, ra = C.ef_compress_batch(e, u, ks)
-        b, rb = C.ef_compress_batch(e, u, ks, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(a.mask), np.asarray(b.mask))
-        np.testing.assert_array_equal(np.asarray(a.values),
-                                      np.asarray(b.values))
-        np.testing.assert_array_equal(np.asarray(ra), np.asarray(rb))
-
-    def test_ef_kernel_route_rejects_custom_compressor(self):
-        """use_kernel=True implements global Top-K only — combining it with
-        a non-global compressor must fail loudly, not silently switch."""
-        u, e, _, ks = _case(3, 1024, seed=11)
-        with pytest.raises(ValueError, match="global Top-K"):
-            C.ef_compress_batch(e, u, ks,
-                                compress_batch=C.block_topk_compress_batch,
-                                use_kernel=True)
-
-    def test_opwa_traced_k_routes_agree(self):
-        u, _, w, ks = _case(8, 2048, seed=12)
-        a = opwa_aggregate_traced_k(u, ks, w, 5.0, 1, use_kernel=False)
-        b = opwa_aggregate_traced_k(u, ks, w, 5.0, 1, use_kernel=True)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 class TestKernelProperty:
@@ -520,7 +477,7 @@ class TestCodecScaleProvenance:
 
 
 class TestCodecKernelParity:
-    """End-to-end aggregate_updates: the codec megakernel route must match
+    """End-to-end compress_merge_leaf: the codec megakernel route must match
     the jnp value_codec path bit for bit — aggregate AND EF residuals."""
 
     @pytest.mark.parametrize("strategy", CODEC_STRATEGIES)

@@ -1,7 +1,8 @@
 """Scanned mesh driver: the pytree-native multi-round `lax.scan` program
 (`engine.make_mesh_sim_scan` / `mesh_round.make_round_body`) must be
-bit-exact with the per-round dispatch loop, carry EF residuals with
-`engine.aggregate_updates` semantics, compile once per checkpoint chunk,
+bit-exact with the per-round dispatch loop, carry EF residuals with the
+flat [C, n] `engine.compress_merge_leaf` semantics, compile once per
+checkpoint chunk,
 and checkpoint/restart without perturbing the trajectory."""
 import numpy as np
 import pytest
@@ -10,10 +11,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import bcrs as bcrs_mod
+from repro.core import strategies
 from repro.core.compression import k_for_ratio, k_for_ratio_traced
 from repro.fed import engine as engine_mod
-from repro.fed.engine import (ClientUpdateSpec, aggregate_updates,
-                              compress_merge_leaf, init_mesh_residuals,
+from repro.fed.engine import (compress_merge_leaf, init_mesh_residuals,
                               make_masked_local_trainer, make_mesh_sim_scan)
 from repro.fed.mesh_round import make_mesh_round_step
 
@@ -131,10 +132,11 @@ class TestScanVsRoundLoop:
 
 
 class TestEFCarrySemantics:
-    def test_matches_aggregate_updates(self):
+    def test_matches_flat_merge(self):
         """On a single flat leaf the per-leaf mesh path and the flat-space
         substrate coincide: the scanned driver's EF residual carry must
-        reproduce `engine.aggregate_updates` round by round, bitwise."""
+        reproduce `compress_merge_leaf` on the flat [C, n] matrix round by
+        round, bitwise."""
         rng = np.random.default_rng(7)
         n, c, s, b, t = 64, 3, 2, 4, 4
         params = {"w": jnp.asarray(rng.normal(size=(n,)), jnp.float32)}
@@ -150,7 +152,7 @@ class TestEFCarrySemantics:
                                  strategy="eftopk")
         out = sim(_copy(params), init_mesh_residuals(params, c), xs)
 
-        spec = ClientUpdateSpec(strategy="eftopk", use_kernel=False)
+        eftopk = strategies.get("eftopk")
         local = make_masked_local_trainer(loss_fn, 1e-2)
         flat = params["w"]
         res = jnp.zeros((c, n), jnp.float32)
@@ -160,9 +162,10 @@ class TestEFCarrySemantics:
                 {"w": flat}, batch_r, xs["step_mask"][r])
             ks = k_for_ratio_traced(n, xs["crs"][r])
             w = jnp.where(xs["active"][r], xs["weights"][r], 0.0)
-            agg, res = aggregate_updates(spec, deltas["w"], w, ks,
-                                         residuals=res,
-                                         active=xs["active"][r])
+            agg, res = compress_merge_leaf(deltas["w"], w, ks, eftopk,
+                                           gamma=5.0, overlap_d=1,
+                                           use_kernel=False, residuals=res,
+                                           active=xs["active"][r])
             flat = flat - agg
         np.testing.assert_array_equal(np.asarray(out["params"]["w"]),
                                       np.asarray(flat))
@@ -209,9 +212,12 @@ class TestCompressMergeLeafKernel:
     def test_auto_and_kernel_match_jnp(self, opwa, ef):
         u, res, w, ks, act = self._inputs()
         r = res if ef else None
-        outs = {uk: compress_merge_leaf(u, w, ks, gamma=3.0, opwa=opwa,
-                                        use_kernel=uk, residuals=r,
-                                        active=act)
+        # grad_sync's convention: residuals switch on EF for any
+        # compressing strategy, so OPWA with EF is bcrs_opwa + residuals
+        strat = strategies.get("bcrs_opwa" if opwa else "topk")
+        outs = {uk: compress_merge_leaf(u, w, ks, strat, gamma=3.0,
+                                        overlap_d=1, use_kernel=uk,
+                                        residuals=r, active=act)
                 for uk in (False, True, "auto")}
         ref_agg, ref_res = outs[False]
         for uk in (True, "auto"):
@@ -226,6 +232,59 @@ class TestCompressMergeLeafKernel:
         from repro.core.compression import resolve_use_kernel
         if jax.devices()[0].platform != "tpu":
             assert resolve_use_kernel("auto") is False
+
+
+class TestLeafLayout:
+    """One function serves both layouts: a rank-3 leaf merges bit-exact
+    with its flat [C, n] view on the jnp route, for every registered
+    strategy (selection, codec and merge reduce over all non-client
+    axes)."""
+
+    @pytest.mark.parametrize("strategy", strategies.names())
+    def test_rank3_matches_flat_view(self, strategy):
+        strat = strategies.get(strategy)
+        rng = np.random.default_rng(11)
+        c, shape = 4, (24, 48)
+        n = shape[0] * shape[1]
+        u = jnp.asarray(rng.normal(size=(c,) + shape), jnp.float32)
+        res = (jnp.asarray(rng.normal(size=(c,) + shape) * 0.3, jnp.float32)
+               if strat.needs_residuals else None)
+        w = jnp.asarray(rng.random(c) + 0.1, jnp.float32)
+        ks = jnp.asarray([1, 57, 400, n], jnp.int32)
+        act = jnp.asarray([True, True, False, True])
+        kw = dict(gamma=3.0, overlap_d=2, use_kernel=False, active=act)
+        agg3, res3 = compress_merge_leaf(u, w, ks, strat, residuals=res,
+                                         **kw)
+        agg2, res2 = compress_merge_leaf(
+            u.reshape(c, n), w, ks, strat,
+            residuals=None if res is None else res.reshape(c, n), **kw)
+        assert agg3.shape == shape
+        np.testing.assert_array_equal(np.asarray(agg3).reshape(n),
+                                      np.asarray(agg2))
+        if res is not None:
+            np.testing.assert_array_equal(np.asarray(res3).reshape(c, n),
+                                          np.asarray(res2))
+
+
+class TestDenseBranch:
+    """Strategies that do not compress take the dense merge: padded cohort
+    slots are gated out of the f32 cast before one weighted sum, whatever
+    the padded rows hold, flat or in a leaf's own layout and dtype."""
+
+    @pytest.mark.parametrize("layout", ["flat", "leaf"])
+    def test_fedavg_gates_padded_slots(self, layout):
+        rng = np.random.default_rng(12)
+        shape = (3000,) if layout == "flat" else (12, 250)
+        dtype = jnp.float32 if layout == "flat" else jnp.bfloat16
+        u = jnp.asarray(rng.normal(size=(5,) + shape), dtype)
+        w = jnp.asarray([0.3, 0.2, 0.5, 0.7, 0.9], jnp.float32)
+        act = jnp.asarray([True, True, True, False, False])
+        agg, res = compress_merge_leaf(u, w, None, strategies.get("fedavg"),
+                                       gamma=5.0, overlap_d=1, active=act)
+        want = jnp.tensordot(w[:3], u[:3].astype(jnp.float32), axes=(0, 0))
+        assert res is None and agg.shape == shape
+        assert agg.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(agg), np.asarray(want))
 
 
 class TestKForRatioHelpers:

@@ -101,11 +101,3 @@ class TestAggregate:
         np.testing.assert_allclose(with_g[hi], no_g[hi], rtol=1e-5)
         lo = counts == 1
         np.testing.assert_allclose(with_g[lo], gamma * no_g[lo], rtol=1e-4)
-
-    def test_kernel_path_matches(self):
-        vals, masks = _sparse_updates(6, 4096, 0.1, seed=7)
-        coeffs = jnp.linspace(0.1, 0.2, 6)
-        a = opwa.opwa_aggregate(vals, masks, coeffs, 5.0, 1, use_kernel=False)
-        b = opwa.opwa_aggregate(vals, masks, coeffs, 5.0, 1, use_kernel=True)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
-                                   atol=1e-6)

@@ -115,16 +115,6 @@ class TestEFBitCompatibility:
         np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
         np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
 
-    def test_block_compress_bitwise(self):
-        updates, _ = self._updates(n=10000, seed=6)
-        crs = np.array([0.05, 0.2, 0.7, 1.0])
-        acfg = AggregationConfig(strategy="bcrs", block_topk=True,
-                                 block_size=2048, use_kernel=False)
-        v1, m1, _ = compress_clients_loop(updates, crs, acfg)
-        v2, m2, _ = compress_clients(updates, crs, acfg)
-        np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
-        np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
-
 
 class TestCompileCount:
     """One fused simulation = O(1) traces of the round program, independent

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import strategies
 from repro.core.aggregation import AggregationConfig
 from repro.fed import engine
 from repro.fed.simulation import (FLSimConfig, _steps_by_client, run_fl,
@@ -185,9 +186,11 @@ class TestStepCap:
 
 
 class TestActiveMaskSemantics:
-    """aggregate_updates with padded inactive rows must equal the compacted
+    """compress_merge_leaf with padded inactive rows must equal the compacted
     computation — in particular the OPWA overlap counts must not see the
     all-True Top-K masks that zero rows produce."""
+
+    KW = dict(gamma=4.0, overlap_d=1, use_kernel=False)
 
     def _case(self, strategy, c_act=3, c_pad=2, n=4096, seed=0):
         key = jax.random.PRNGKey(seed)
@@ -197,25 +200,25 @@ class TestActiveMaskSemantics:
         w = jnp.concatenate([w_act, jnp.zeros((c_pad,))])
         ks = jnp.full((c_act + c_pad,), 128, jnp.int32)
         active = jnp.asarray([True] * c_act + [False] * c_pad)
-        spec = engine.ClientUpdateSpec(strategy=strategy, gamma=4.0)
-        return spec, u, u_act, w, w_act, ks, active
+        return strategies.get(strategy), u, u_act, w, w_act, ks, active
 
     @pytest.mark.parametrize("strategy", ["fedavg", "topk", "bcrs_opwa"])
     def test_padded_equals_compacted(self, strategy):
-        spec, u, u_act, w, w_act, ks, active = self._case(strategy)
-        agg_pad, _ = engine.aggregate_updates(spec, u, w, ks, active=active)
-        agg_cmp, _ = engine.aggregate_updates(spec, u_act, w_act, ks[:3])
+        strat, u, u_act, w, w_act, ks, active = self._case(strategy)
+        agg_pad, _ = engine.compress_merge_leaf(u, w, ks, strat, active=active,
+                                                **self.KW)
+        agg_cmp, _ = engine.compress_merge_leaf(u_act, w_act, ks[:3], strat,
+                                                **self.KW)
         np.testing.assert_array_equal(np.asarray(agg_pad),
                                       np.asarray(agg_cmp))
 
     def test_eftopk_inactive_residuals_pass_through(self):
-        spec, u, u_act, w, w_act, ks, active = self._case("eftopk")
+        strat, u, u_act, w, w_act, ks, active = self._case("eftopk")
         res = jax.random.normal(jax.random.PRNGKey(7), u.shape) * 0.1
-        agg_pad, r_pad = engine.aggregate_updates(spec, u, w, ks,
-                                                  residuals=res,
-                                                  active=active)
-        agg_cmp, r_cmp = engine.aggregate_updates(spec, u_act, w_act, ks[:3],
-                                                  residuals=res[:3])
+        agg_pad, r_pad = engine.compress_merge_leaf(
+            u, w, ks, strat, residuals=res, active=active, **self.KW)
+        agg_cmp, r_cmp = engine.compress_merge_leaf(
+            u_act, w_act, ks[:3], strat, residuals=res[:3], **self.KW)
         np.testing.assert_array_equal(np.asarray(agg_pad),
                                       np.asarray(agg_cmp))
         np.testing.assert_array_equal(np.asarray(r_pad[:3]),
