@@ -21,15 +21,33 @@ block, a loop walks the lanes ``fold * 128`` at a time and stacks the fold
 lane-slices of the ``(C, 128)`` rows onto the sublanes (``fold = 8 // C``;
 sublane ``k * C + c`` holds client c's lanes ``k * 128 ...``), so a small
 cohort still fills its ``(8, 128)`` vregs: whole at C = 1, 2, 4 and 8.
+Each slice (with its EF residual added first) is placed by a sublane
+rotation and a select: a ``concatenate`` lowers to a store to and a
+shuffled load from one VMEM scratch, which chains every chunk behind the
+last.
 
-Counts. For each of the W-1 = 15 candidate boundaries ``lo + j*step`` the
-loop adds ``bits >= b_j`` into an int32 vreg accumulator of the stacked
-shape: vreg adds only, no cross-lane reduction inside a step. The
-accumulators live in VMEM scratch across the sweep's blocks (a per-lane
-count is at most n / 128, so int32 cannot overflow) and are reduced once,
-at the sweep's last block, to per-client counts [C, W-1]. The largest
-qualifying boundary (count >= k) then becomes the new ``lo``: after 8 sweeps
-the interval width is 1 and ``lo`` is exactly the k-th-largest bit pattern
+Counts. The W-1 = 15 candidate boundaries are ``lo + j*step`` with
+``step = 2^shift``, ``shift = max(27 - 4s, 0)`` in sweep s. Each element's
+digit ``d = clamp((bits - lo) >> shift, 0, 16)`` (int32: both terms are
+below 2^31) satisfies ``d >= j`` exactly when ``bits >= lo + j*step``, so
+one digit answers all 15 boundaries. The counts are packed (SWAR): ``d`` is
+replicated into the four bytes of an int32 word, and for the four words q
+the byte i of ``((D + K_q) >> 7) & 0x01010101`` is ``d >= j`` for
+``j = 4q + i + 1``, where byte i of ``K_q`` holds ``128 - j``: ``d + 128 -
+j`` lies in [112, 143], so bit 7 is the answer and no carry crosses a byte
+(``j = 16`` gets a zero byte of ``K_3`` and never counts). One int32
+array of byte counters, four words of the stacked shape, takes the adds:
+vreg ops only, about 16 for the 15 boundaries where compare, select and
+add took 45. A byte gains at most 1 per chunk and holds 255, so the loop
+flushes the bytes into the int32 VMEM accumulators ``[W-1, ...]`` after
+every ``SEGMENT`` = 15 groups of ``UNROLL`` = 16 chunks (and after the
+remainder loop). The accumulators are byte-major (row ``_ROW0[i] + q``
+counts byte i of word q), so a flush is four slices. They live across
+the sweep's blocks (a per-lane count is at most n / 128, so int32 cannot
+overflow) and are reduced once, at the sweep's last block, to per-client
+counts [C, W-1]. The largest qualifying boundary (count >= k) then
+becomes the new ``lo``: after 8 sweeps the interval width is 1 and
+``lo`` is exactly the k-th-largest bit pattern
 (ties kept), bit-identical to the 32-halving reference for every k in
 [1, n]. The interval width is client-independent (width_s = 2^31 / 16^s),
 so the boundary spacing is recomputed from ``program_id(0)``; ``lo [C, 1]``
@@ -56,10 +74,11 @@ scale verbatim (docs/DESIGN.md §10).
 
 Padding contract: tail lanes past the real ``n`` must be zero, and ``n`` a
 multiple of ``TILE_N`` = 128. Candidate boundaries are always >= 1
-(``step >= 1``, ``j >= 1``), so zero lanes are never counted and the
-thresholds are those of the unpadded rows. A last block that overhangs the
-operand holds undefined data past ``n`` on the TPU; its lanes are masked by
-position to bit pattern 0, which the same argument covers.
+(``step >= 1``, ``j >= 1``), so a zero lane's digit is 0: it is never
+counted, and the thresholds are those of the unpadded rows. A last block
+that overhangs the operand holds undefined data past ``n`` on the TPU; its
+lanes are masked by position to bit pattern 0, which the same argument
+covers.
 """
 from __future__ import annotations
 
@@ -67,7 +86,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -84,8 +102,25 @@ TILE_N = LANES
 BLOCK_BYTES = 4 << 20
 #: folded chunks counted per iteration of the in-block loop
 UNROLL = 16
-#: initial boundary spacing: span 2^31 split into WAYS buckets
-_STEP0 = np.uint32((1 << 31) // WAYS)
+#: unrolled groups between flushes of the byte counters: a byte gains at
+#: most 1 per chunk and holds 255
+SEGMENT = 255 // UNROLL
+#: log2 of the initial boundary spacing: span 2^31 split into WAYS buckets
+_SHIFT0 = 31 - (WAYS - 1).bit_length()
+#: one in each byte of an int32 word
+_ONES = 0x01010101
+#: packed words of byte counters for the WAYS - 1 boundaries
+_WORDS = -(-(WAYS - 1) // 4)
+#: per word q, byte i holds 128 - j for boundary j = 4q + i + 1, so bit 7
+#: of that byte of ``d * _ONES + K_q`` is ``d >= j``; a j past the last
+#: boundary gets 0 and never sets it (d <= WAYS < 128)
+_PACK = tuple(sum((128 - j if j < WAYS else 0) << (8 * i)
+                  for i, j in enumerate(range(4 * q + 1, 4 * q + 5)))
+              for q in range(_WORDS))
+#: words whose byte i holds a boundary (j = 4q + i + 1 < WAYS); the int32
+#: accumulators are byte-major: row ``_ROW0[i] + q`` counts that j
+_SPAN = tuple(len(range(i + 1, WAYS, 4)) for i in range(4))
+_ROW0 = tuple(sum(_SPAN[:i]) for i in range(4))
 
 
 def _fold(c: int) -> int:
@@ -129,22 +164,41 @@ def _threshold_find_kernel(has_res: bool, emit_scale: bool, n: int,
         if emit_scale:
             mx_ref[...] = jnp.zeros_like(mx_ref)
 
-    def load(ref, off):
-        """Lanes [off, off + fold*128) of every client as (fold*C, 128):
-        lane-slice k lands on sublanes k*C .. k*C + C - 1."""
-        parts = [ref[:, pl.ds(off + k * LANES, LANES)].astype(jnp.float32)
-                 for k in range(fold)]
-        return parts[0] if fold == 1 else jnp.concatenate(parts, axis=0)
-
     stacked = (fold * c, LANES)
+    row = jax.lax.broadcasted_iota(jnp.int32, stacked, 0)
+    # sublanes from k*C on, where lane-slice k and the later ones land
+    above = [row >= k * c for k in range(fold)]
+
+    def load(off):
+        """The selection quantity's lanes [off, off + fold*128) of every
+        client as (fold*C, 128): lane-slice k lands on sublanes k*C ..
+        k*C + C - 1 (rotated and selected into place, not concatenated:
+        see the module docstring)."""
+        out = None
+        for k in range(fold):
+            part = x_ref[:, pl.ds(off + k * LANES, LANES)].astype(jnp.float32)
+            if has_res:
+                part = (e_ref[:, pl.ds(off + k * LANES, LANES)]
+                        .astype(jnp.float32) + part)
+            if fold == 1:
+                return part
+            part = jax.lax.pad(part, 0.0, ((0, (fold - 1) * c, 0), (0, 0, 0)))
+            if k:
+                part = pltpu.roll(part, k * c, 0)
+            out = part if out is None else jnp.where(above[k], part, out)
+        return out
+
     # interval width is client-independent: width_s = 2^31 / 16^s, so the
-    # boundary spacing needs no cross-sweep state (floor(ceil) identities:
-    # widths are powers of two until the final width-8 -> step-1 sweep)
-    step = jnp.maximum(_STEP0 >> (4 * s.astype(jnp.uint32)), jnp.uint32(1))
-    lo = jnp.broadcast_to(lo_ref[...], (c, LANES))
+    # boundary spacing 2^shift needs no cross-sweep state (widths are
+    # powers of two until the final width-8 -> step-1 sweep)
+    shift = jnp.maximum(_SHIFT0 - 4 * s, 0)
+    step = jnp.left_shift(jnp.uint32(1), shift.astype(jnp.uint32))
+    # lo < 2^31, so it is the same number as int32
+    lo = jnp.broadcast_to(
+        jax.lax.bitcast_convert_type(lo_ref[...], jnp.int32), (c, LANES))
     lo = lo if fold == 1 else jnp.concatenate([lo] * fold, axis=0)
-    # the W-1 candidate boundaries lo + j*step, one stacked vreg each
-    bounds = jnp.stack([lo + jnp.uint32(j) * step for j in range(1, WAYS)])
+    # the packing constants K_q, one stacked vreg each
+    pack = jnp.stack([jnp.full(stacked, k, jnp.int32) for k in _PACK])
 
     # chunks of this block: the last block may overhang n, and then only
     # the remainder loop (which masks by position) reaches past its end
@@ -153,41 +207,55 @@ def _threshold_find_kernel(has_res: bool, emit_scale: bool, n: int,
     masked = last != width
     if masked:
         # lane position within a chunk of each element of the stacked vregs
-        row = jax.lax.broadcasted_iota(jnp.int32, stacked, 0)
         pos0 = jax.lax.broadcasted_iota(jnp.int32, stacked, 1)
         for k in range(1, fold):
-            pos0 = pos0 + jnp.where(row >= k * c, LANES, 0)
+            pos0 = pos0 + jnp.where(above[k], LANES, 0)
         final = t == nt - 1
         groups = jnp.where(final, last // lanes // UNROLL, full // UNROLL)
         chunks = jnp.where(final, -(-last // lanes), full)
     else:
         groups, chunks = full // UNROLL, full
+    segments = (groups + SEGMENT - 1) // SEGMENT
 
     def count(with_max: bool):
         def chunk(off, carry, mask):
-            v = load(x_ref, off)
-            if has_res:
-                v = load(e_ref, off) + v
-            a = jnp.abs(v)
+            a = jnp.abs(load(off))
             if mask:                      # lanes past n: undefined data
                 a = jnp.where(t * width + off + pos0 < n, a, 0.0)
-            bits = jax.lax.bitcast_convert_type(a, jnp.uint32)[None]
-            acc = carry[0] + (bits >= bounds).astype(jnp.int32)
-            return (acc, jnp.maximum(carry[1], a)) if with_max else (acc,)
+            bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+            # the digit: d >= j exactly when bits >= lo + j*step
+            d = jnp.minimum(jnp.maximum((bits - lo) >> shift, 0), WAYS) * _ONES
+            words = carry[0] + (((d[None] + pack) >> 7) & _ONES)
+            return (words, jnp.maximum(carry[1], a)) if with_max else (words,)
 
         def unrolled(i, carry):
             base = pl.multiple_of(i * (UNROLL * lanes), UNROLL * lanes)
-            for u in range(UNROLL):
-                carry = chunk(base + u * lanes, carry, False)
-            return carry
+            # traced once and lowered UNROLL times: every process traces
+            # the kernel before the compile cache is looked up
+            return jax.lax.fori_loop(
+                0, UNROLL,
+                lambda u, cr: chunk(base + u * lanes, cr, False),
+                carry, unroll=True)
 
         def single(i, carry):
             return chunk(pl.multiple_of(i * lanes, lanes), carry, masked)
 
-        carry = (acc_ref[...], mx_ref[...]) if with_max else (acc_ref[...],)
-        carry = jax.lax.fori_loop(0, groups, unrolled, carry)
+        zero = jnp.zeros((_WORDS,) + stacked, jnp.int32)
+
+        def flush(carry):
+            """Byte counters into the int32 accumulators, then zeroed."""
+            for i, (r0, span) in enumerate(zip(_ROW0, _SPAN)):
+                acc_ref[pl.ds(r0, span)] += (carry[0][:span] >> 8 * i) & 0xFF
+            return (zero,) + carry[1:]
+
+        def segment(g, carry):
+            end = jnp.minimum((g + 1) * SEGMENT, groups)
+            return flush(jax.lax.fori_loop(g * SEGMENT, end, unrolled, carry))
+
+        carry = (zero, mx_ref[...]) if with_max else (zero,)
+        carry = jax.lax.fori_loop(0, segments, segment, carry)
         carry = jax.lax.fori_loop(groups * UNROLL, chunks, single, carry)
-        acc_ref[...] = carry[0]
+        carry = flush(carry)
         if with_max:
             mx_ref[...] = carry[-1]
 
@@ -209,8 +277,9 @@ def _threshold_find_kernel(has_res: bool, emit_scale: bool, n: int,
 
     @pl.when(t == nt - 1)
     def _():
-        cnt = jnp.concatenate([per_client(acc_ref.at[j], jnp.add, jnp.sum)
-                               for j in range(WAYS - 1)], axis=1)
+        cnt = jnp.concatenate(
+            [per_client(acc_ref.at[_ROW0[j % 4] + j // 4], jnp.add, jnp.sum)
+             for j in range(WAYS - 1)], axis=1)
         k = ks_ref[...]                                     # [C, 1] i32
         qual = cnt >= k                                     # [C, W-1]
         # the boundary index is reduced in int32: Mosaic has no unsigned

@@ -73,6 +73,32 @@ class TestThresholdFind:
         np.testing.assert_array_equal(np.asarray(th),
                                       np.asarray(ref.threshold_find_ref(u, ks)))
 
+    @pytest.mark.parametrize("sweep", [1, 4, 7])
+    def test_digit_boundaries(self, sweep):
+        """Magnitudes on each candidate boundary ``lo + j*step`` of one sweep
+        (j = 0..17) and one bit pattern either side, one far above, zeros
+        below, with the threshold at several digits of that sweep: pins the
+        digit's clamp at both ends and the j = 16 byte that never counts."""
+        step = 1 << max(27 - 4 * sweep, 0)
+        span = 1 << (31 - 4 * sweep)               # the sweep's interval
+        lo = 0x3F800000 // span * span
+        vals = np.unique([lo + j * step + d for j in range(18)
+                          for d in (-1, 0, 1)] + [0x7F000000])
+        targets = np.unique([lo, lo + step - 1, lo + step,
+                             lo + span // 2 + 1, lo + span - 1])
+        c, n = len(targets), 512
+        rng = np.random.default_rng(sweep)
+        bits = np.zeros((c, n), np.uint32)
+        for r in range(c):
+            bits[r, rng.permutation(n)[:len(vals)]] = vals
+        u = bits.view(np.float32) * rng.choice([-1.0, 1.0], (c, n))
+        u = jnp.asarray(u, jnp.float32)
+        ks = jnp.asarray([np.sum(vals >= t) for t in targets], jnp.int32)
+        th = threshold_find_pallas(u, ks.reshape(c, 1), interpret=True)
+        np.testing.assert_array_equal(np.asarray(th)[:, 0], targets)
+        np.testing.assert_array_equal(np.asarray(th),
+                                      np.asarray(ref.threshold_find_ref(u, ks)))
+
     def test_wrapper_pads_ragged_n(self):
         u, e, _, ks = _case(5, 700, seed=3)
         th = ops.topk_thresholds(u, ks)
@@ -96,14 +122,16 @@ class TestThresholdFindTiling:
     """The block/chunk tiling of threshold_find, bit for bit against the
     32-halving reference: k=1, k=n, ties straddling the threshold across
     blocks, and an all-zero row, through the kernel and its padding
-    wrapper."""
+    wrapper. The "saturated" case fills the byte counters: one row is a
+    huge magnitude at all but a few lanes, with k = n, so every boundary of
+    every sweep counts one per chunk and a missed flush shows."""
 
     @pytest.mark.parametrize("mode", ["plain", "ef", "ef_scale"])
-    @pytest.mark.parametrize("size", ["blocks", "leaf"])
-    @pytest.mark.parametrize("c", [1, 4, 9])
+    @pytest.mark.parametrize("size", ["blocks", "leaf", "saturated"])
+    @pytest.mark.parametrize("c", [1, 4, 9, 16])
     def test_vs_ref(self, c, size, mode):
         ef = mode != "plain"
-        n = _tiled_width(c, size, ef)
+        n = _tiled_width(c, "leaf" if size == "leaf" else "blocks", ef)
         u, e, _, ks = _case(c, n, seed=c + n)
         # 300 tied magnitudes spread over every block, with k inside them
         ties = np.arange(300) * (n // 300)
@@ -114,6 +142,12 @@ class TestThresholdFindTiling:
             u = u.at[1].set(0.0)                   # all-zero row
             e = e.at[1].set(0.0)
             ks = ks.at[2].set(1).at[c - 1].set(n)
+        if size == "saturated":
+            few = np.arange(5) * (n // 5) + 7
+            big = jnp.where(np.arange(n) % 2 == 0, 3e38, -3e38)
+            u = u.at[0].set(big.at[few].set(1e-30))
+            e = e.at[0].set(0.0)
+            ks = ks.at[0].set(n)
         res = e if ef else None
         want = np.asarray(ref.threshold_find_ref(u, ks, res))
         if mode == "ef_scale":

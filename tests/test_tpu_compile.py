@@ -23,6 +23,9 @@ C, N = 8, 2048 * 5632
 #: many blocks of either kernel) and a norm-sized leaf (2048, one block,
 #: already a multiple of megakernel_aggregate's 1024 padding)
 C_MERGE, N_EMBED, N_NORM = 4, 100352 * 2048, 2048
+#: a width whose last block and last folded chunk are partial, so the
+#: kernels' masked remainder path is compiled too
+N_RAGGED = N + 3 * 1024 + 384
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +71,9 @@ CASES = {
     "threshold_find_norm": (_tf_plain, ("x", "ks"), (C_MERGE, N_NORM)),
     "threshold_find_ef_scale_norm": (_tf_ef_scale, ("x", "ks", "x"),
                                      (C_MERGE, N_NORM)),
+    "threshold_find_ragged": (_tf_plain, ("x", "ks"), (C_MERGE, N_RAGGED)),
+    "threshold_find_ef_scale_ragged": (_tf_ef_scale, ("x", "ks", "x"),
+                                       (C_MERGE, N_RAGGED)),
     "fused_merge_opwa": (_fm_opwa, ("x", "th", "col", "col"), (C, N)),
     "fused_merge_opwa_embedding": (_fm_opwa, ("x", "th", "col", "col"),
                                    (C_MERGE, N_EMBED)),
